@@ -120,6 +120,9 @@ def main(argv=None) -> int:
         help="also write a JSON perf snapshot (rows + headline metrics)",
     )
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     wanted = [w for w in args.only.split(",") if w]
     unknown = [w for w in wanted if w not in SECTIONS]
     if unknown:

@@ -18,6 +18,7 @@ import jax
 import numpy as np
 
 from repro import pipelines as PP
+from repro.compile_cache import enable_compile_cache
 from repro.core import StripeSplitter
 from repro.raster import as_source, make_spot6_pair
 
@@ -30,6 +31,7 @@ def main():
                     help="output path (.rtif flat strip-parallel file, or "
                          ".rtic for the tiled pyramidal container)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     out = args.out or str(Path(tempfile.mkdtemp()) / "pansharpened.rtif")
     xs, pan = make_spot6_pair(args.xs_rows, args.xs_cols)

@@ -10,9 +10,12 @@ whole-image run.
 import numpy as np
 
 from repro import pipelines as PP
+from repro.compile_cache import enable_compile_cache
 from repro.core import AutoSplitter, Pipeline
 from repro.filters import BandStatistics, ndvi
 from repro.raster import MemoryMapper, SyntheticScene
+
+enable_compile_cache()
 
 # 1. wire the graph: 4-band synthetic Spot6-like scene → NDVI → stats → sink
 p = Pipeline()

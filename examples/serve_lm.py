@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, reduced
 from repro.models import lm
 from repro.serve import ServeEngine
@@ -27,6 +28,7 @@ def main():
     ap.add_argument("--new-tokens", type=int, default=24)
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = dataclasses.replace(reduced(get_config(args.arch)), n_layers=4)
     params = lm.init_params(cfg, jax.random.PRNGKey(0))

@@ -12,6 +12,7 @@ import argparse
 import dataclasses
 import tempfile
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, reduced
 from repro.data import SyntheticTokens
 from repro.train.loop import LoopConfig, Trainer
@@ -24,6 +25,7 @@ def main():
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = dataclasses.replace(
         reduced(get_config("qwen1.5-0.5b")),

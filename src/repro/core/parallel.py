@@ -80,12 +80,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax>=0.8 exposes shard_map at top level
-    shard_map = jax.shard_map  # type: ignore[attr-defined]
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.execplan import PlanCache
 from repro.core.pipeline import Pipeline
@@ -735,15 +730,15 @@ class ParallelExecutor:
         out_specs = (P(ar, ac, None), P())  # states fully reduced → replicated
 
         def make_program():
-            # check_rep=False: shard_map has no replication rule for
-            # pallas_call (the plan-layer Pallas fast path traces one into
-            # the worker body).  The skipped check only guards the claim
-            # that P() outputs are replicated — ours come from psum-style
-            # collectives in _combine_collective, so it holds by
+            # check_vma=False: the worker body may trace a pallas_call (the
+            # plan-layer Pallas fast path), whose outputs carry no
+            # varying-manual-axes annotation.  The skipped check only guards
+            # the claim that P() outputs are replicated — ours come from
+            # psum-style collectives in _combine_collective, so it holds by
             # construction.
-            fn = shard_map(
+            fn = jax.shard_map(
                 worker, mesh=self.mesh, in_specs=in_specs,
-                out_specs=out_specs, check_rep=False,
+                out_specs=out_specs, check_vma=False,
             )
             return jax.jit(fn)
 
@@ -751,7 +746,13 @@ class ParallelExecutor:
         # executor on the same pipeline/geometry/devices reuses one program
         key = self.plan.program_key + (tuple(d.id for d in self.devices),)
         jitted = self.plan_cache.get_or_build(key, make_program)
-        globals_ = [self._padded_global(ss) for ss in plan.source_tiles]
+        # place each source's tiles on their own devices up front (one
+        # transfer per shard, never the whole scene through one device)
+        tiled = NamedSharding(self.mesh, P(ar, ac, None))
+        globals_ = [
+            jax.device_put(self._padded_global(ss), tiled)
+            for ss in plan.source_tiles
+        ]
         return jitted, globals_
 
     def run(self, keep_outputs: bool = False):

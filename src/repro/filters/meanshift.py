@@ -25,23 +25,33 @@ from repro.core.region import ImageRegion
 
 
 def meanshift_ref(x: jnp.ndarray, hs: int, hr: float, n_iter: int) -> jnp.ndarray:
-    """x: (H + 2hs, W + 2hs, B) pre-padded → (H, W, B)."""
+    """x: (H + 2hs, W + 2hs, B) pre-padded → (H, W, B).
+
+    The window offsets accumulate in row-major order and the squared range
+    distance sums in band order — the Pallas kernel's order.  A flat range
+    kernel turns one rounding difference at its ``hr`` threshold into a
+    whole neighbor gained or lost, so the oracle fixes the order instead of
+    leaving it to a reduction."""
     H = x.shape[0] - 2 * hs
     W = x.shape[1] - 2 * hs
     x = x.astype(jnp.float32)
-    # stack the (2hs+1)² spatially shifted windows: (H, W, K, B)
-    shifts = []
-    for dr in range(-hs, hs + 1):
-        for dc in range(-hs, hs + 1):
-            shifts.append(x[hs + dr : hs + dr + H, hs + dc : hs + dc + W])
-    win = jnp.stack(shifts, axis=2)
-    v = x[hs : hs + H, hs : hs + W]
+    bands = [x[..., b] for b in range(x.shape[-1])]
+    v = [xb[hs : hs + H, hs : hs + W] for xb in bands]
     hr2 = hr * hr
     for _ in range(n_iter):
-        d2 = ((win - v[:, :, None, :]) ** 2).sum(axis=-1)  # (H, W, K)
-        w = (d2 <= hr2).astype(jnp.float32)[..., None]
-        v = (win * w).sum(axis=2) / jnp.maximum(w.sum(axis=2), 1e-12)
-    return v
+        num = [jnp.zeros((H, W), jnp.float32) for _ in bands]
+        den = jnp.zeros((H, W), jnp.float32)
+        for u in range(2 * hs + 1):
+            for w in range(2 * hs + 1):
+                xw = [xb[u : u + H, w : w + W] for xb in bands]
+                d2 = (xw[0] - v[0]) ** 2
+                for a, c in zip(xw[1:], v[1:]):
+                    d2 = d2 + (a - c) ** 2
+                m = (d2 <= hr2).astype(jnp.float32)
+                num = [n + a * m for n, a in zip(num, xw)]
+                den = den + m
+        v = [n / jnp.maximum(den, 1e-12) for n in num]
+    return jnp.stack(v, axis=-1)
 
 
 class MeanShift(Filter):

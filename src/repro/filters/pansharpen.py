@@ -17,15 +17,23 @@ import numpy as np
 
 from repro.core.process_object import Filter, ImageInfo
 from repro.core.region import ImageRegion
-from repro.filters.texture import box_sum
 
 
 def pansharpen_ref(xs_up: jnp.ndarray, pan: jnp.ndarray, radius: int) -> jnp.ndarray:
-    """xs_up: (H, W, B); pan: (H + 2r, W + 2r, 1) pre-padded. → (H, W, B)."""
+    """xs_up: (H, W, B); pan: (H + 2r, W + 2r, 1) pre-padded. → (H, W, B).
+
+    The box sum adds the (2r+1)² shifted windows in order: exact for
+    integer-valued PAN at any width, where a cumulative-sum filter's running
+    totals outgrow float32's 24-bit significand on a wide strip."""
     k = 2 * radius + 1
-    smooth = box_sum(pan.astype(jnp.float32), radius) / (k * k)
-    p = pan[radius : pan.shape[0] - radius, radius : pan.shape[1] - radius]
-    ratio = p.astype(jnp.float32) / jnp.maximum(smooth, 1e-6)
+    H, W = xs_up.shape[:2]
+    pan = pan.astype(jnp.float32)
+    acc = jnp.zeros((H, W) + pan.shape[2:], jnp.float32)
+    for u in range(k):
+        for v in range(k):
+            acc = acc + pan[u : u + H, v : v + W]
+    smooth = acc / (k * k)
+    ratio = pan[radius : radius + H, radius : radius + W] / jnp.maximum(smooth, 1e-6)
     return xs_up.astype(jnp.float32) * ratio
 
 

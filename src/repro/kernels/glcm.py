@@ -3,18 +3,18 @@
 Hardware adaptation (GPU→TPU): per-window co-occurrence histograms are
 scatter workloads on GPU (atomics into shared-memory bins).  TPUs have no
 fast scatter, so the histogram is rebuilt as *vectorized one-hot
-accumulation*: for each of the (2R+1)² window offsets, the pair code
-``q1·Q + q2`` is compared against a static iota over the Q² bins and added
-into a VMEM accumulator — pure VPU work with perfectly regular access, no
-atomics, no gather.  Features then come from static per-bin weight vectors
-(VPU reductions over the bin axis).
+counting*: the pair codes ``q1·Q + q2`` of the (2R+1)² window offsets are
+computed once per tile, then a loop over the Q² bins counts each bin's
+matches and folds its share into the Haralick moments — pure VPU work on
+lane-dense (th, tw) planes, no atomics, no gather, and no (th, tw, Q²)
+accumulator.
 
 Grid: one program per (tile_r, tile_c) output tile; inputs are pre-tiled
 with halos host-side (`kernels.util.extract_patches`), so every block is a
-self-contained VMEM working set:
-
-    q tile    (T + 2·halo)²·4B     e.g. (128+8)²·4 ≈ 74 KB
-    acc       T²·Q²·4B             128²·64·4 = 4 MB  (Q=8)  « 128 MB VMEM
+self-contained VMEM working set.  The raw input block is band-last, so its
+band axis pads to 128 lanes: (64+6)·(128+6)·128·4B ≈ 4.8 MB per buffer at
+the default (64, 128) tile, under the 16 MiB scoped-VMEM default with
+double buffering.  The output block is feature-planar, (5, th, tw).
 """
 from __future__ import annotations
 
@@ -23,13 +23,13 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.kernels.util import (
     extract_patches,
     interpret_default,
-    stitch_patches,
+    stitch_planar,
+    widen_unsigned,
 )
 
 
@@ -39,7 +39,7 @@ def _glcm_kernel(x_ref, out_ref, *, radius, offset, levels, vmin, vmax,
     dr, dc = offset
     m = max(abs(dr), abs(dc))
     halo = radius + m
-    x = x_ref[0]  # raw (th + 2·halo, tw + 2·halo[, B]) tile
+    x = widen_unsigned(x_ref[0])  # raw (th + 2·halo, tw + 2·halo[, B]) tile
     # fused pre-stage: the upstream pointwise chain (and band selection)
     # runs on the VMEM tile, then quantization — all inside the kernel, so
     # neither the chain's intermediates nor the int32 levels ever hit HBM
@@ -50,35 +50,50 @@ def _glcm_kernel(x_ref, out_ref, *, radius, offset, levels, vmin, vmax,
         levels - 1,
     ).astype(jnp.int32)
 
-    nbins = levels * levels
-    acc = jnp.zeros((th, tw, nbins), jnp.float32)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, 1, nbins), 2)
-    # window loop is static: (2R+1)² one-hot accumulations
-    for u in range(-radius, radius + 1):
-        for v in range(-radius, radius + 1):
-            q1 = jax.lax.dynamic_slice(q, (halo + u, halo + v), (th, tw))
-            q2 = jax.lax.dynamic_slice(q, (halo + u + dr, halo + v + dc), (th, tw))
-            code = (q1 * levels + q2)[:, :, None]
-            acc = acc + (code == iota).astype(jnp.float32)
+    # pair codes q1·Q + q2 of the (2R+1)² window offsets (static slices)
+    codes = [
+        q[halo + u:halo + u + th, halo + v:halo + v + tw] * levels
+        + q[halo + u + dr:halo + u + dr + th, halo + v + dc:halo + v + dc + tw]
+        for u in range(-radius, radius + 1)
+        for v in range(-radius, radius + 1)
+    ]
+    # every offset lands in exactly one bin: the window total is static
+    total = float(len(codes))
 
-    # Haralick features from static bin-weight vectors
-    i = jnp.arange(levels, dtype=jnp.float32)
-    ii = jnp.repeat(i, levels)  # bin → row level
-    jj = jnp.tile(i, levels)  # bin → col level
-    total = jnp.maximum(acc.sum(-1, keepdims=True), 1e-12)
-    p = acc / total
-    energy = (p * p).sum(-1)
-    entropy = -(p * jnp.log(p + 1e-12)).sum(-1)
-    contrast = (p * ((ii - jj) ** 2)).sum(-1)
-    homog = (p / (1.0 + (ii - jj) ** 2)).sum(-1)
-    mu_i = (p * ii).sum(-1)
-    mu_j = (p * jj).sum(-1)
-    var_i = (p * ii * ii).sum(-1) - mu_i * mu_i
-    var_j = (p * jj * jj).sum(-1) - mu_j * mu_j
-    cov = (p * ii * jj).sum(-1) - mu_i * mu_j
+    def add_bin(b, sums):
+        # one co-occurrence bin for the whole tile: its count over the
+        # window, then its share of each Haralick moment
+        count = (codes[0] == b).astype(jnp.float32)
+        for c in codes[1:]:
+            count = count + (c == b).astype(jnp.float32)
+        p = count / total
+        i = (b // levels).astype(jnp.float32)  # bin → row level
+        j = (b % levels).astype(jnp.float32)  # bin → col level
+        energy, ent, contrast, homog, mu_i, mu_j, s_ii, s_jj, s_ij = sums
+        return (
+            energy + p * p,
+            ent + p * jnp.log(p + 1e-12),
+            contrast + p * ((i - j) ** 2),
+            homog + p / (1.0 + (i - j) ** 2),
+            mu_i + p * i,
+            mu_j + p * j,
+            s_ii + p * i * i,
+            s_jj + p * j * j,
+            s_ij + p * i * j,
+        )
+
+    zero = jnp.zeros((th, tw), jnp.float32)
+    energy, ent, contrast, homog, mu_i, mu_j, s_ii, s_jj, s_ij = (
+        jax.lax.fori_loop(0, levels * levels, add_bin, (zero,) * 9)
+    )
+    var_i = s_ii - mu_i * mu_i
+    var_j = s_jj - mu_j * mu_j
+    cov = s_ij - mu_i * mu_j
     denom2 = var_i * var_j
     corr = jnp.where(denom2 < 1e-4, 0.0, cov / jnp.sqrt(jnp.maximum(denom2, 1e-4)))
-    out_ref[0] = jnp.stack([energy, entropy, contrast, homog, corr], axis=-1)
+    # feature-planar output block: each feature is a lane-dense (th, tw) plane
+    for k, feat in enumerate((energy, -ent, contrast, homog, corr)):
+        out_ref[0, k] = feat
 
 
 @functools.partial(
@@ -95,7 +110,7 @@ def glcm_features(
     levels: int = 8,
     vmin: float = 0.0,
     vmax: float = 4096.0,
-    tile: Tuple[int, int] = (128, 128),
+    tile: Tuple[int, int] = (64, 128),
     interpret: Optional[bool] = None,
     pre_fn=None,
 ) -> jnp.ndarray:
@@ -136,9 +151,9 @@ def glcm_features(
         kernel,
         grid=(ntr * ntc,),
         in_specs=[pl.BlockSpec(blk, lambda i, _n=nd: (i,) + (0,) * (_n - 1))],
-        out_specs=pl.BlockSpec((1, th, tw, 5), lambda i: (i, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((ntr * ntc, th, tw, 5), jnp.float32),
+        out_specs=pl.BlockSpec((1, 5, th, tw), lambda i: (i, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((ntr * ntc, 5, th, tw), jnp.float32),
         interpret=interpret,
         name="glcm_haralick",
     )(patches)
-    return stitch_patches(out.reshape(ntr, ntc, th, tw, 5), H, W)
+    return stitch_planar(out, ntr, ntc, H, W)

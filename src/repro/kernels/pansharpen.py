@@ -2,10 +2,12 @@
 
 Fuses the PAN box smoothing, the ratio, and the per-band multiply into one
 VMEM pass — the unfused jnp path materializes smooth(PAN) and the ratio in
-HBM (3 extra full-image round trips).  Box sum uses the running cumsum
-formulation along rows/cols inside the tile.
+HBM (3 extra full-image round trips).  The box sum adds the shifted
+windows in the jnp reference's order, so the two agree bit for bit.
 
-VMEM per tile (T=256, r=2, B=4): pan (T+4)²·4 ≈ 270 KB, xs 256²·4·4 = 1 MB.
+Band-last input blocks pad their band axis to 128 lanes in VMEM: at the
+default (16, 128) tile, xs 16·128·128·4B = 1 MB and pan (16+4)·(128+4)·
+128·4B ≈ 1.4 MB per buffer.  The output block is band-planar, (B, th, tw).
 """
 from __future__ import annotations
 
@@ -16,7 +18,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.util import extract_patches, interpret_default, stitch_patches
+from repro.kernels.util import (
+    extract_patches,
+    interpret_default,
+    stitch_planar,
+    widen_unsigned,
+)
 
 
 def _ps_kernel(xs_ref, pan_ref, out_ref, *, radius, tile, pre_xs, pre_pan):
@@ -25,11 +32,11 @@ def _ps_kernel(xs_ref, pan_ref, out_ref, *, radius, tile, pre_xs, pre_pan):
     # fused pre-stages: upstream pointwise chains run on the VMEM tiles; the
     # PAN band is selected here (after the chain), so the raw multiband tile
     # streams in once and nothing intermediate touches HBM
-    pan = pan_ref[0]
+    pan = widen_unsigned(pan_ref[0])
     if pre_pan is not None:
         pan = pre_pan(pan)
     pan = pan[..., 0].astype(jnp.float32)  # (th+2r, tw+2r)
-    xs = xs_ref[0]
+    xs = widen_unsigned(xs_ref[0])
     if pre_xs is not None:
         xs = pre_xs(xs)
     xs = xs.astype(jnp.float32)  # (th, tw, B)
@@ -37,11 +44,13 @@ def _ps_kernel(xs_ref, pan_ref, out_ref, *, radius, tile, pre_xs, pre_pan):
     acc = jnp.zeros((th, tw), jnp.float32)
     for u in range(k):
         for v in range(k):
-            acc = acc + jax.lax.dynamic_slice(pan, (u, v), (th, tw))
+            acc = acc + pan[u:u + th, v:v + tw]
     smooth = acc / (k * k)
-    center = jax.lax.dynamic_slice(pan, (radius, radius), (th, tw))
+    center = pan[radius:radius + th, radius:radius + tw]
     ratio = center / jnp.maximum(smooth, 1e-6)
-    out_ref[0] = xs * ratio[:, :, None]
+    # band-planar output block: one lane-dense (th, tw) plane per band
+    for b in range(xs.shape[-1]):
+        out_ref[0, b] = xs[..., b] * ratio
 
 
 @functools.partial(
@@ -51,7 +60,7 @@ def pansharpen(
     xs_up: jnp.ndarray,
     pan: jnp.ndarray,
     radius: int = 2,
-    tile: Tuple[int, int] = (256, 256),
+    tile: Tuple[int, int] = (16, 128),
     interpret: Optional[bool] = None,
     pre_xs=None,
     pre_pan=None,
@@ -98,9 +107,9 @@ def pansharpen(
                 lambda i: (i, 0, 0, 0),
             ),
         ],
-        out_specs=pl.BlockSpec((1, th, tw, B), lambda i: (i, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((ntr * ntc, th, tw, B), jnp.float32),
+        out_specs=pl.BlockSpec((1, B, th, tw), lambda i: (i, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((ntr * ntc, B, th, tw), jnp.float32),
         interpret=interpret,
         name="pansharpen_rcs",
     )(xs_tiles, pan_tiles)
-    return stitch_patches(out.reshape(ntr, ntc, th, tw, B), H, W)
+    return stitch_planar(out, ntr, ntc, H, W)
