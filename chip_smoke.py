@@ -81,7 +81,7 @@ def stream(pair, cache, splitter):
 
 
 def report(name: str, shape, res, cold: float, warm: float, err: float) -> None:
-    snap = res.cache_snapshot or {}
+    snap = res.cache_stats.snapshot() if res.cache_stats is not None else {}
     print(
         f"{name}: scene {'x'.join(map(str, shape))} "
         f"regions={res.regions_processed} compiles={snap.get('compiles')} "

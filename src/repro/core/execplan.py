@@ -385,8 +385,8 @@ class PlanCache:
     def stats_snapshot(self) -> Dict[str, int]:
         """The registry counters as a plain dict (see
         :meth:`CacheStats.snapshot`).  This is the supported way to read the
-        counters for metrics/benchmarks — ``StreamResult.cache_snapshot`` and
-        the serving engine's ``metrics()`` both surface exactly this."""
+        counters for metrics/benchmarks; the serving engine's ``metrics()``
+        surfaces exactly this."""
         return self.stats.snapshot()
 
     def warm(

@@ -85,6 +85,7 @@ from repro.core.scheduling import (
     work_stealing_schedule,
 )
 from repro.core.splitting import Splitter, StripeSplitter
+from repro.core.tracing import span
 
 _SCHEDULERS = ("static", "lpt", "work_stealing")
 
@@ -165,10 +166,6 @@ class StreamResult:
     #: This is the LIVE CacheStats object — it keeps counting after the run
     #: (documented behavior, see ``reset_global_plan_cache``).
     cache_stats: Optional[CacheStats] = None
-    #: the same counters frozen at run end as a plain dict
-    #: (``PlanCache.stats_snapshot()``) — what metrics/benchmarks should
-    #: read instead of reaching into the live counters
-    cache_snapshot: Optional[Dict[str, int]] = None
 
 
 class StreamingExecutor:
@@ -232,9 +229,10 @@ class StreamingExecutor:
         # describe pass only; the O(graph) closure tree is lowered by the
         # registry on misses — cache hits never rebuild it.  Virtual geometry
         # (when safe) folds border strips onto the interior signature.
-        desc = self.pipeline.describe_pull(
-            self.mapper, region, virtual=self.describe_virtual
-        )
+        with span("describe", row0=region.row0, col0=region.col0):
+            desc = self.pipeline.describe_pull(
+                self.mapper, region, virtual=self.describe_virtual
+            )
         if self.region_gate is not None:
             # block (on the prefetch thread) until the input rows this region
             # actually reads are committed by the upstream stage
@@ -248,7 +246,8 @@ class StreamingExecutor:
     def run(self, keep_outputs: bool = False) -> StreamResult:
         pipeline, mapper = self.pipeline, self.mapper
         info = pipeline.info(mapper)
-        mapper.begin(info)
+        with span("begin"):
+            mapper.begin(info)
 
         # persistent-filter state lives across regions (paper's Reset)
         pstates = {p.name: p.reset() for p in pipeline.persistent_nodes()}
@@ -274,16 +273,25 @@ class StreamingExecutor:
         def compute(prep) -> np.ndarray:
             nonlocal pstates
             plan, fn, arrays = prep
-            out, pstates = fn(arrays, pstates, plan.origins())
-            if self.region_gate is not None:
-                # pacing-only release (the data lives on disk): fire once the
-                # region's pixels are produced and handed to the write stage
-                self.region_gate.done(plan)
-            return np.asarray(out)
+            r = plan.out_region
+            # the device's own time is busy, not idle: inside ``dispatch``
+            # only the program's launch and completion latency read as idle
+            with span("dispatch", row0=r.row0, col0=r.col0):
+                out, pstates = fn(arrays, pstates, plan.origins())
+                if self.region_gate is not None:
+                    # pacing-only release (the data lives on disk): fire once
+                    # the region's pixels are produced and handed to the
+                    # write stage
+                    self.region_gate.done(plan)
+                jax.block_until_ready(out)
+            with span("d2h", row0=r.row0, col0=r.col0, bytes=out.nbytes):
+                return np.asarray(out)
 
         def produce_sync(region: ImageRegion) -> np.ndarray:
             if compiled_path:
-                return compute(self._prepare(region))
+                with span("wait_inputs", row0=region.row0, col0=region.col0):
+                    prep = self._prepare(region)
+                return compute(prep)
             if self.region_gate is not None:
                 # non-compiled paths still gate on the described reads (the
                 # gate clamps virtual row spill to the committed extent)
@@ -305,7 +313,8 @@ class StreamingExecutor:
             else:
                 for region in regions:
                     data = produce_sync(region)
-                    mapper.consume(region, data)
+                    with span("wait_write", row0=region.row0, col0=region.col0):
+                        mapper.consume(region, data)
                     pixels += region.num_pixels
                     if keep_outputs:
                         outputs.append(data)
@@ -320,16 +329,14 @@ class StreamingExecutor:
         presults = {
             p.name: p.synthesize(pstates[p.name]) for p in pipeline.persistent_nodes()
         }
-        mapper.end()
+        with span("end"):
+            mapper.end()
         return StreamResult(
             regions_processed=len(regions),
             pixels_processed=pixels,
             persistent_results=presults,
             outputs=outputs if keep_outputs else None,
             cache_stats=self.plan_cache.stats if compiled_path else None,
-            cache_snapshot=(
-                self.plan_cache.stats_snapshot() if compiled_path else None
-            ),
         )
 
     def _run_async(self, regions, compute, outputs, keep_outputs) -> int:
@@ -357,20 +364,23 @@ class StreamingExecutor:
                 fill()
                 while pending:
                     region, fut = pending.popleft()
-                    prep = fut.result()
+                    with span("wait_inputs", row0=region.row0, col0=region.col0):
+                        prep = fut.result()
                     fill()  # keep the read window full while we compute
                     data = compute(prep)
                     pixels += region.num_pixels
                     if keep_outputs:
                         outputs.append(data)
-                    writer.put(region, data)
+                    with span("wait_write", row0=region.row0, col0=region.col0):
+                        writer.put(region, data)
             except BaseException as e:  # noqa: BLE001 — re-raised below
                 error = e
             finally:
                 for _, fut in pending:
                     fut.cancel()
                 try:
-                    writer.close()
+                    with span("wait_write"):
+                        writer.close()
                 except BaseException as e:  # noqa: BLE001
                     if error is None:
                         error = e
@@ -543,7 +553,6 @@ def run_pool(
             else None
         ),
         cache_stats=cache.stats if use_jit else None,
-        cache_snapshot=cache.stats_snapshot() if use_jit else None,
     )
 
 

@@ -44,6 +44,7 @@ import numpy as np
 
 from repro.core.process_object import GeoTransform, ImageInfo, Mapper, Source
 from repro.core.region import ImageRegion, tile_cover, whole
+from repro.core.tracing import span
 from repro.raster.protocol import (
     CAP_PYRAMIDAL,
     CAP_RANGE_READABLE,
@@ -474,7 +475,8 @@ class TileWriter(Mapper, RasterSink):
             for r, c in self._dims[1:]
         ]
 
-    def _append(self, level: int, ty: int, tx: int, buf: np.ndarray) -> None:
+    def _append(self, level: int, ty: int, tx: int, buf: np.ndarray) -> int:
+        """Append one tile to the file; returns the bytes written."""
         raw = np.ascontiguousarray(buf).tobytes()
         offset = self._next_offset
         self._next_offset += len(raw)
@@ -486,8 +488,14 @@ class TileWriter(Mapper, RasterSink):
         self._index[level][f"{ty},{tx}"] = [
             self._next_offset - len(raw), len(raw)
         ]
+        return len(raw)
 
     def consume(self, out_region: ImageRegion, data: np.ndarray) -> None:
+        with span("consume", row0=out_region.row0, col0=out_region.col0) as sp:
+            sp.set_metadata(bytes=self._consume(out_region, data))
+
+    def _consume(self, out_region: ImageRegion, data: np.ndarray) -> int:
+        """Scatter one region into tiles; returns the bytes appended."""
         info = self._info
         data = np.ascontiguousarray(
             np.asarray(data), dtype=self._dtype
@@ -495,6 +503,7 @@ class TileWriter(Mapper, RasterSink):
         full = info.full_region
         if not full.contains(out_region):
             raise ValueError(f"consume {out_region} outside image {full}")
+        written = 0
         with self._lock:
             for ty, tx, tile in tile_cover(
                 out_region, self.tile_rows, self.tile_cols, bounds=full
@@ -515,7 +524,7 @@ class TileWriter(Mapper, RasterSink):
                 ]
                 entry[1] += ov.num_pixels
                 if entry[1] >= tile.num_pixels:
-                    self._append(0, ty, tx, entry[0])
+                    written += self._append(0, ty, tx, entry[0])
                     del self._pending[(ty, tx)]
             # overview pyramid: level L keeps full-res pixels at multiples of
             # 2**L (the DecimatedSource sampling grid), scattered as strided
@@ -532,16 +541,24 @@ class TileWriter(Mapper, RasterSink):
                 self._ov[lv - 1][
                     r0 : r0 + sub.shape[0], c0 : c0 + sub.shape[1]
                 ] = sub
+        return written
 
     def end(self) -> None:
         if self._fd is None:
             return
+        with span("flush") as sp:
+            sp.set_metadata(bytes=self._flush())
+
+    def _flush(self) -> int:
+        """Seal the file: pending tiles, the overview pyramid, the index and
+        the header; returns the bytes written."""
         info = self._info
+        written = 0
         with self._lock:
             # partially-covered level-0 tiles flush as-is (uncovered pixels
             # stay zero — same semantics as an under-covered MemoryMapper)
             for (ty, tx), (buf, _) in sorted(self._pending.items()):
-                self._append(0, ty, tx, buf)
+                written += self._append(0, ty, tx, buf)
             self._pending.clear()
             for lv in range(1, len(self._dims)):
                 lr, lc = self._dims[lv]
@@ -549,7 +566,9 @@ class TileWriter(Mapper, RasterSink):
                     whole(lr, lc), self.tile_rows, self.tile_cols,
                     bounds=whole(lr, lc),
                 ):
-                    self._append(lv, ty, tx, self._ov[lv - 1][tile.slices()])
+                    written += self._append(
+                        lv, ty, tx, self._ov[lv - 1][tile.slices()]
+                    )
             index_payload = json.dumps(
                 {
                     "levels": [
@@ -559,7 +578,7 @@ class TileWriter(Mapper, RasterSink):
                 }
             ).encode()
             index_offset = self._next_offset
-            os.pwrite(self._fd, index_payload, index_offset)
+            written += os.pwrite(self._fd, index_payload, index_offset)
             meta = {
                 "rows": info.rows,
                 "cols": info.cols,
@@ -581,6 +600,7 @@ class TileWriter(Mapper, RasterSink):
             head = TILED_MAGIC + json.dumps(meta).encode()
             if len(head) > TILED_HEADER_BYTES:
                 raise ValueError("RTIC header overflow")
-            os.pwrite(self._fd, head.ljust(TILED_HEADER_BYTES, b"\0"), 0)
+            written += os.pwrite(self._fd, head.ljust(TILED_HEADER_BYTES, b"\0"), 0)
             os.close(self._fd)
             self._fd = None
+        return written
