@@ -34,8 +34,8 @@ in one of them or in that small remainder:
   blocked while the write-behind queue is full, or, with ``prefetch=0``,
   the inline ``mapper.consume``; without an origin, the drain of the
   write-behind queue at the end of the pass;
-- ``end``: ``mapper.end`` after the last strip (a file sink flushes what is
-  pending, its overviews and its index).
+- ``end``: ``mapper.end`` after the last strip (a file sink writes its
+  index and seals its header).
 
 The prefetch threads (with ``prefetch=0``, the dispatching thread, inside
 ``wait_inputs``):
@@ -46,10 +46,12 @@ The thread that runs ``TileWriter.consume`` (the write-behind thread, or
 the dispatching thread with ``prefetch=0``) and the one that runs its
 ``end``:
 
-- ``consume`` (``row0``, ``col0``, ``bytes``): one region scattered into
-  tiles; ``bytes`` are the finished tiles appended to the file;
-- ``flush`` (``bytes``): ``TileWriter.end``; ``bytes`` are the pending
-  tiles, the overview pyramid, the index and the header it writes.
+- ``consume`` (``row0``, ``col0``, ``bytes``, ``ranges``): one region
+  written straight to its tiles' byte ranges at every pyramid level;
+  ``bytes`` are the pixel bytes written, ``ranges`` the byte ranges, one
+  per ``pwrite``/``pwritev`` call;
+- ``flush`` (``bytes``): ``TileWriter.end``; ``bytes`` are the index and
+  the header it writes.
 
 Over one pass, the ``consume`` and ``flush`` bytes add up to the size of
 the finished file, and the ``d2h`` bytes to the output's bytes.

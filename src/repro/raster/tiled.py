@@ -24,10 +24,11 @@ length)``): :class:`FileRangeReader` serves a local file via ``os.pread``;
 assembles windows from cached tiles and prefetches scheduled tiles on a
 background thread (``read_ahead`` — the streaming engine hands it the region
 schedule, overlapping range fetches with compute).  :class:`TileWriter` is
-the matching sink: it buffers consumed regions into tiles, appends each tile
-the moment its pixels are fully covered, accumulates the overview pyramid,
-and seals header + footer on ``end()`` — ``TileWriter`` output is exactly
-what ``TiledSource`` ingests (round-trip property test in
+the matching sink: ``begin()`` fixes every tile's byte range at every level,
+each consumed region is written straight to those ranges (its level-0 rows
+and its decimated overview pixels, no staging tiles), and ``end()`` writes
+the footer index and seals the header — ``TileWriter`` output is exactly
+what ``TiledSource`` ingests (round-trip and byte-for-byte oracle tests in
 ``tests/test_tiled_io.py``).
 """
 from __future__ import annotations
@@ -409,21 +410,53 @@ class TiledSource(Source, RasterSource):
 
 # -- the sink ----------------------------------------------------------------
 
+#: most buffers one ``os.pwritev`` call takes
+_IOV_MAX = os.sysconf("SC_IOV_MAX")
+#: columns interleaved at a time: a block's rows of one band stay in cache
+_INTERLEAVE_COLS = 256
+
+
+def _pwritev(fd: int, bufs: list, offset: int) -> int:
+    """Write ``bufs`` back to back from ``offset``; returns the calls made."""
+    calls = 0
+    while bufs:
+        batch = bufs[:_IOV_MAX]
+        n = os.pwritev(fd, batch, offset)
+        calls += 1
+        offset += n
+        if n == sum(map(len, batch)):
+            bufs = bufs[len(batch):]
+            continue
+        if n == 0:
+            raise OSError(f"pwritev wrote nothing at offset {offset}")
+        # a short write: go on from the first byte not written
+        i = 0
+        while n >= len(batch[i]):
+            n -= len(batch[i])
+            i += 1
+        bufs = [batch[i][n:]] + bufs[i + 1:]
+    return calls
+
 
 class TileWriter(Mapper, RasterSink):
     """Writes consumed regions into a fresh RTIC container.
 
-    Level-0 pixels are scattered into per-tile buffers; a tile is appended to
-    the file the moment its pixels are fully covered (bounding writer memory
-    to the tiles a region cover currently straddles — regions need not align
-    with the tile grid, any disjoint cover works).  The overview pyramid
-    accumulates in memory (geometric series, < 1/3 of the image) and is
-    flushed with the footer index on ``end()``.  ``levels`` counts total
-    pyramid levels including full resolution; the default adds levels until
-    the coarsest fits in one tile (capped at 9).
+    ``begin`` fixes every tile's byte range, level by level in row-major
+    tile order, and sizes the file to the end of the tile area.  Each
+    consumed region is then written straight to its final ranges: the rows
+    of its level-0 pixels, and its decimated pixels at every overview level
+    (gathered one whole pixel at a time), from views of the region's data
+    (data in another layout, such as a strip pulled from a device, is first
+    interleaved into a buffer the consuming thread keeps).
+    Regions need not align with the tile grid; any disjoint cover, in any
+    order and from several threads at once, gives the same file, and pixels
+    no region covered read as zero.  ``end`` writes the footer index after
+    the tile area and seals the header.  ``levels`` counts total pyramid
+    levels including full resolution; the default adds levels until the
+    coarsest fits in one tile (capped at 9).
     """
 
-    thread_safe = True  # consume() is lock-guarded; pwrite appends are serial
+    thread_safe = True  # disjoint regions write disjoint byte ranges
 
     def __init__(
         self,
@@ -461,87 +494,116 @@ class TileWriter(Mapper, RasterSink):
             _level_dims(info.rows, info.cols, lv) for lv in range(n_levels)
         ]
         self._dtype = np.dtype(info.dtype)
+        #: one pixel, all bands, as a single element
+        self._pixel = np.dtype((np.void, info.bands * self._dtype.itemsize))
+        #: per level: {"ty,tx": [offset, length]}, fixed from here on
+        self._index: List[Dict[str, List[int]]] = []
+        offset = TILED_HEADER_BYTES
+        for r, c in self._dims:
+            tiles = {}
+            for ty, tx, tile in tile_cover(
+                whole(r, c), self.tile_rows, self.tile_cols, bounds=whole(r, c)
+            ):
+                length = tile.num_pixels * self._pixel.itemsize
+                tiles[f"{ty},{tx}"] = [offset, length]
+                offset += length
+            self._index.append(tiles)
+        self._index_offset = offset
         self._fd = os.open(
             self.path, os.O_RDWR | os.O_CREAT | os.O_TRUNC, 0o644
         )
-        os.pwrite(self._fd, b"\0" * TILED_HEADER_BYTES, 0)  # sealed on end()
-        self._next_offset = TILED_HEADER_BYTES
-        self._lock = threading.Lock()
-        #: level-0 pending tiles: (ty, tx) -> [buffer, covered_pixels]
-        self._pending: Dict[Tuple[int, int], list] = {}
-        self._index: List[Dict[str, List[int]]] = [{} for _ in range(n_levels)]
-        self._ov = [
-            np.zeros((r, c, info.bands), dtype=self._dtype)
-            for r, c in self._dims[1:]
-        ]
-
-    def _append(self, level: int, ty: int, tx: int, buf: np.ndarray) -> int:
-        """Append one tile to the file; returns the bytes written."""
-        raw = np.ascontiguousarray(buf).tobytes()
-        offset = self._next_offset
-        self._next_offset += len(raw)
-        view = memoryview(raw)
-        while view:
-            written = os.pwrite(self._fd, view, offset)
-            view = view[written:]
-            offset += written
-        self._index[level][f"{ty},{tx}"] = [
-            self._next_offset - len(raw), len(raw)
-        ]
-        return len(raw)
+        # the header and any tile no region covers stay holes: zeros
+        os.ftruncate(self._fd, offset)
+        #: each consuming thread's interleave buffer
+        self._local = threading.local()
 
     def consume(self, out_region: ImageRegion, data: np.ndarray) -> None:
         with span("consume", row0=out_region.row0, col0=out_region.col0) as sp:
-            sp.set_metadata(bytes=self._consume(out_region, data))
+            written, ranges = self._consume(out_region, data)
+            sp.set_metadata(bytes=written, ranges=ranges)
 
-    def _consume(self, out_region: ImageRegion, data: np.ndarray) -> int:
-        """Scatter one region into tiles; returns the bytes appended."""
-        info = self._info
-        data = np.ascontiguousarray(
-            np.asarray(data), dtype=self._dtype
-        ).reshape(out_region.rows, out_region.cols, info.bands)
-        full = info.full_region
+    def _consume(
+        self, out_region: ImageRegion, data: np.ndarray
+    ) -> Tuple[int, int]:
+        """Write one region's pixels at every level; returns the bytes and
+        the byte ranges written."""
+        full = self._info.full_region
         if not full.contains(out_region):
             raise ValueError(f"consume {out_region} outside image {full}")
-        written = 0
-        with self._lock:
-            for ty, tx, tile in tile_cover(
-                out_region, self.tile_rows, self.tile_cols, bounds=full
-            ):
-                ov = tile.intersect(out_region)
-                entry = self._pending.get((ty, tx))
-                if entry is None:
-                    entry = [
-                        np.zeros(
-                            (tile.rows, tile.cols, info.bands),
-                            dtype=self._dtype,
-                        ),
-                        0,
-                    ]
-                    self._pending[(ty, tx)] = entry
-                entry[0][ov.relative_to(tile).slices()] = data[
-                    ov.relative_to(out_region).slices()
-                ]
-                entry[1] += ov.num_pixels
-                if entry[1] >= tile.num_pixels:
-                    written += self._append(0, ty, tx, entry[0])
-                    del self._pending[(ty, tx)]
-            # overview pyramid: level L keeps full-res pixels at multiples of
-            # 2**L (the DecimatedSource sampling grid), scattered as strided
-            # views of this region's data
-            for lv in range(1, len(self._dims)):
-                f = 1 << lv
-                r_start = (-out_region.row0) % f
-                c_start = (-out_region.col0) % f
-                sub = data[r_start::f, c_start::f]
-                if sub.size == 0:
-                    continue
-                r0 = (out_region.row0 + r_start) // f
-                c0 = (out_region.col0 + c_start) // f
-                self._ov[lv - 1][
-                    r0 : r0 + sub.shape[0], c0 : c0 + sub.shape[1]
-                ] = sub
-        return written
+        pix = self._pixels(out_region, data)
+        region = out_region
+        written = ranges = 0
+        for lv in range(len(self._dims)):
+            if lv:
+                # level L keeps the full-resolution pixels at multiples of
+                # 2**L (the DecimatedSource sampling grid): the even rows
+                # and columns of level L - 1
+                r_start, c_start = region.row0 % 2, region.col0 % 2
+                pix = np.ascontiguousarray(pix[r_start::2, c_start::2])
+                region = ImageRegion(
+                    ((region.row0 + r_start) // 2, (region.col0 + c_start) // 2),
+                    pix.shape,
+                )
+            if region.is_empty():
+                break
+            n, k = self._write_level(lv, region, pix)
+            written += n
+            ranges += k
+        return written, ranges
+
+    def _pixels(self, region: ImageRegion, data: np.ndarray) -> np.ndarray:
+        """The region's pixels as a C-ordered ``(rows, cols)`` array of whole
+        pixels.  A strip pulled from a device comes in the device's layout
+        (rows or bands fastest, not bands); it is interleaved a block of
+        columns and one band at a time, which keeps numpy's strided copy in
+        cache, into a buffer the calling thread keeps, so its pages are
+        touched once and not for every region."""
+        arr = np.asarray(data).reshape(region.rows, region.cols, self._info.bands)
+        if arr.dtype != self._dtype or not arr.flags.c_contiguous:
+            buf = getattr(self._local, "buf", None)
+            if buf is None or buf.size < arr.size:
+                buf = self._local.buf = np.empty(arr.size, self._dtype)
+            out = buf[: arr.size].reshape(arr.shape)
+            for c0 in range(0, region.cols, _INTERLEAVE_COLS):
+                cols = slice(c0, c0 + _INTERLEAVE_COLS)
+                for b in range(arr.shape[2]):
+                    out[:, cols, b] = arr[:, cols, b]
+            arr = out
+        return arr.view(self._pixel).reshape(region.rows, region.cols)
+
+    def _write_level(
+        self, lv: int, region: ImageRegion, pix: np.ndarray
+    ) -> Tuple[int, int]:
+        """Write ``pix``, the pixels of ``region`` of level ``lv``, into that
+        level's tiles; returns the bytes and the byte ranges written."""
+        px = self._pixel.itemsize
+        src = memoryview(pix.reshape(-1).view(np.uint8))
+        stride = region.cols * px
+        tiles = self._index[lv]
+        written = ranges = 0
+        for ty, tx, tile in tile_cover(
+            region, self.tile_rows, self.tile_cols,
+            bounds=whole(*self._dims[lv]),
+        ):
+            ov = tile.intersect(region)
+            width = ov.cols * px
+            first = (
+                (ov.row0 - region.row0) * stride + (ov.col0 - region.col0) * px
+            )
+            rows = [
+                src[s : s + width]
+                for s in range(first, first + ov.rows * stride, stride)
+            ]
+            dst = tiles[f"{ty},{tx}"][0] + (
+                (ov.row0 - tile.row0) * tile.cols + ov.col0 - tile.col0
+            ) * px
+            if ov.cols == tile.cols:  # whole tile rows: one range
+                ranges += _pwritev(self._fd, rows, dst)
+            else:
+                for i, row in enumerate(rows):
+                    ranges += _pwritev(self._fd, [row], dst + i * tile.cols * px)
+            written += ov.rows * width
+        return written, ranges
 
     def end(self) -> None:
         if self._fd is None:
@@ -550,57 +612,41 @@ class TileWriter(Mapper, RasterSink):
             sp.set_metadata(bytes=self._flush())
 
     def _flush(self) -> int:
-        """Seal the file: pending tiles, the overview pyramid, the index and
-        the header; returns the bytes written."""
+        """Seal the file: the index after the tile area, then the header;
+        returns the bytes written."""
         info = self._info
-        written = 0
-        with self._lock:
-            # partially-covered level-0 tiles flush as-is (uncovered pixels
-            # stay zero — same semantics as an under-covered MemoryMapper)
-            for (ty, tx), (buf, _) in sorted(self._pending.items()):
-                written += self._append(0, ty, tx, buf)
-            self._pending.clear()
-            for lv in range(1, len(self._dims)):
-                lr, lc = self._dims[lv]
-                for ty, tx, tile in tile_cover(
-                    whole(lr, lc), self.tile_rows, self.tile_cols,
-                    bounds=whole(lr, lc),
-                ):
-                    written += self._append(
-                        lv, ty, tx, self._ov[lv - 1][tile.slices()]
-                    )
-            index_payload = json.dumps(
-                {
-                    "levels": [
-                        {"rows": r, "cols": c, "tiles": self._index[lv]}
-                        for lv, (r, c) in enumerate(self._dims)
-                    ]
-                }
-            ).encode()
-            index_offset = self._next_offset
-            written += os.pwrite(self._fd, index_payload, index_offset)
-            meta = {
-                "rows": info.rows,
-                "cols": info.cols,
-                "bands": info.bands,
-                "dtype": self._dtype.str,
-                "geo": [
-                    info.geo.origin_x,
-                    info.geo.origin_y,
-                    info.geo.spacing_x,
-                    info.geo.spacing_y,
-                ],
-                "nodata": info.nodata,
-                "tile_rows": self.tile_rows,
-                "tile_cols": self.tile_cols,
-                "levels": len(self._dims),
-                "index_offset": index_offset,
-                "index_length": len(index_payload),
+        index_payload = json.dumps(
+            {
+                "levels": [
+                    {"rows": r, "cols": c, "tiles": self._index[lv]}
+                    for lv, (r, c) in enumerate(self._dims)
+                ]
             }
-            head = TILED_MAGIC + json.dumps(meta).encode()
-            if len(head) > TILED_HEADER_BYTES:
-                raise ValueError("RTIC header overflow")
-            written += os.pwrite(self._fd, head.ljust(TILED_HEADER_BYTES, b"\0"), 0)
-            os.close(self._fd)
-            self._fd = None
-        return written
+        ).encode()
+        _pwritev(self._fd, [index_payload], self._index_offset)
+        meta = {
+            "rows": info.rows,
+            "cols": info.cols,
+            "bands": info.bands,
+            "dtype": self._dtype.str,
+            "geo": [
+                info.geo.origin_x,
+                info.geo.origin_y,
+                info.geo.spacing_x,
+                info.geo.spacing_y,
+            ],
+            "nodata": info.nodata,
+            "tile_rows": self.tile_rows,
+            "tile_cols": self.tile_cols,
+            "levels": len(self._dims),
+            "index_offset": self._index_offset,
+            "index_length": len(index_payload),
+        }
+        head = TILED_MAGIC + json.dumps(meta).encode()
+        if len(head) > TILED_HEADER_BYTES:
+            raise ValueError("RTIC header overflow")
+        _pwritev(self._fd, [head.ljust(TILED_HEADER_BYTES, b"\0")], 0)
+        os.close(self._fd)
+        self._fd = None
+        self._local = None
+        return len(index_payload) + TILED_HEADER_BYTES

@@ -9,8 +9,11 @@ protocol coercers / capability flags / deprecated free-function wrappers,
 ``run_pipeline(sink=...)``, the catalog layer behind P8/P9, and the
 streamed-then-SPMD zero-new-lowers guarantee over a TiledSource.
 """
+import json
 import os
+import random
 import time
+from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 import pytest
@@ -155,8 +158,8 @@ def _check_roundtrip(tmp_path_factory, rows, cols, tile_r, tile_c,
         h = min(strip_rows, rows - r0)
         strips.append((ImageRegion((r0, 0), (h, cols)), data[r0:r0 + h]))
         r0 += h
-    # consume order must not matter (tiles append when fully covered,
-    # stragglers flush on end)
+    # consume order must not matter (every tile's byte range is fixed in
+    # begin, and each region is written straight into the tiles it meets)
     for region, block in reversed(strips) if reverse else strips:
         w.consume(region, block)
     w.end()
@@ -176,7 +179,7 @@ def _check_roundtrip(tmp_path_factory, rows, cols, tile_r, tile_c,
 
 def test_tile_unaligned_partial_covers(tmp_path):
     """Disjoint non-strip covers (2-D tiles smaller than the container's
-    tile grid) still reassemble exactly — pending buffers merge them."""
+    tile grid) still reassemble exactly: each block's rows land in place."""
     path = str(tmp_path / "t.rtic")
     data = _rand(21, 19, 2, seed=5)
     info = ImageInfo(21, 19, 2, data.dtype)
@@ -189,6 +192,121 @@ def test_tile_unaligned_partial_covers(tmp_path):
     src = TiledSource(path)
     try:
         np.testing.assert_array_equal(src.read_region(), data)
+    finally:
+        src.close()
+
+
+def _oracle_file(data, tile_r, tile_c, levels, geo):
+    """The expected RTIC file, built from the whole array: the header, the
+    tiles of every level in level-major, row-major order, the index."""
+    rows, cols, bands = data.shape
+    if levels is None:  # add levels until the coarsest fits one tile, <= 9
+        levels = 1
+        while levels < 9 and max(
+            -(-rows // 2 ** (levels - 1)), -(-cols // 2 ** (levels - 1))
+        ) > max(tile_r, tile_c):
+            levels += 1
+    blobs, index, offset = [], [], 4096
+    for lv in range(levels):
+        img = data[:: 2 ** lv, :: 2 ** lv]
+        tiles = {}
+        for ty in range(-(-img.shape[0] // tile_r)):
+            for tx in range(-(-img.shape[1] // tile_c)):
+                blob = img[ty * tile_r:(ty + 1) * tile_r,
+                           tx * tile_c:(tx + 1) * tile_c].tobytes()
+                tiles[f"{ty},{tx}"] = [offset, len(blob)]
+                blobs.append(blob)
+                offset += len(blob)
+        index.append({"rows": img.shape[0], "cols": img.shape[1],
+                      "tiles": tiles})
+    payload = json.dumps({"levels": index}).encode()
+    meta = {
+        "rows": rows, "cols": cols, "bands": bands, "dtype": data.dtype.str,
+        "geo": [geo.origin_x, geo.origin_y, geo.spacing_x, geo.spacing_y],
+        "nodata": None, "tile_rows": tile_r, "tile_cols": tile_c,
+        "levels": levels, "index_offset": offset,
+        "index_length": len(payload),
+    }
+    head = (b"RTIC0001" + json.dumps(meta).encode()).ljust(4096, b"\0")
+    return head + b"".join(blobs) + payload
+
+
+def _cover(kind, rows, cols, tile_r):
+    """Disjoint regions covering a rows x cols image."""
+    full = whole(rows, cols)
+    if kind == "whole":
+        return [full]
+    if kind in ("strips", "device", "threads"):  # full width, off the grid
+        h = tile_r + 3 if kind != "threads" else max(1, tile_r // 2 + 1)
+        return [ImageRegion((r, 0), (min(h, rows - r), cols))
+                for r in range(0, rows, h)]
+    blocks = [t for _, _, t in tile_cover(full, 5, 7, bounds=full)]
+    random.Random(rows * 31 + cols).shuffle(blocks)  # unaligned, shuffled
+    return blocks
+
+
+@pytest.mark.parametrize(
+    "cover", ["whole", "strips", "device", "blocks", "threads"]
+)
+@pytest.mark.parametrize(
+    "rows,cols,tile_r,tile_c,bands,dtype,levels",
+    [
+        (37, 29, 8, 8, 1, np.uint8, None),      # ragged right and bottom
+        (50, 37, 16, 13, 3, np.float32, 3),     # tile_rows != tile_cols
+        (64, 48, 16, 16, 5, np.uint16, 1),      # aligned, full resolution only
+        (23, 61, 8, 12, 2, np.float32, None),   # wide, auto levels
+        (1, 1, 4, 4, 4, np.uint8, 2),           # one pixel
+        (45, 34, 32, 7, 4, np.uint16, 2),       # tile taller than a strip
+    ],
+)
+def test_file_bytes_match_oracle_for_every_cover(
+    tmp_path, cover, rows, cols, tile_r, tile_c, bands, dtype, levels
+):
+    """Any disjoint cover, in any order or from several threads at once,
+    gives the oracle's file byte for byte.  ``device`` and ``threads`` hand
+    over column-major strips, the layout a TPU's strips come back in."""
+    data = _rand(rows, cols, bands, dtype=dtype, seed=rows + cols + bands)
+    geo = GeoTransform(1.0, 2.0, 6.0, -6.0)
+    path = str(tmp_path / "c.rtic")
+    w = TileWriter(path, tile_r, tile_c, levels=levels)
+    w.begin(ImageInfo(rows, cols, bands, data.dtype, geo))
+    regions = _cover(cover, rows, cols, tile_r)
+    layout = np.asfortranarray if cover in ("device", "threads") else np.asarray
+    if cover == "threads":
+        with ThreadPoolExecutor(4) as pool:
+            list(pool.map(
+                lambda r: w.consume(r, layout(data[r.slices()])), regions
+            ))
+    else:
+        for region in regions:
+            w.consume(region, layout(data[region.slices()]))
+    w.end()
+    with open(path, "rb") as f:
+        got = f.read()
+    assert got == _oracle_file(data, tile_r, tile_c, levels, geo)
+
+
+def test_under_covered_pixels_read_zero(tmp_path):
+    """Pixels no region covered read back as zero at every level, and the
+    file opens even with whole tiles never touched."""
+    data = _rand(40, 35, 2, dtype=np.uint16, seed=12)
+    path = str(tmp_path / "u.rtic")
+    w = TileWriter(path, tile_rows=8, tile_cols=8)
+    w.begin(ImageInfo(40, 35, 2, data.dtype))
+    want = np.zeros_like(data)
+    for region in (ImageRegion((0, 0), (13, 35)), ImageRegion((20, 5), (9, 11)),
+                   ImageRegion((33, 30), (7, 5))):
+        w.consume(region, data[region.slices()])
+        want[region.slices()] = data[region.slices()]
+    w.end()
+    src = TiledSource(path)
+    try:
+        assert src._c.n_levels == 4
+        for lv in range(src._c.n_levels):
+            np.testing.assert_array_equal(
+                TiledSource(src._c, level=lv).read_region(),
+                want[:: 2 ** lv, :: 2 ** lv],
+            )
     finally:
         src.close()
 

@@ -1,8 +1,10 @@
 """Program spans (``repro.core.tracing``) of the streaming executor and the
 tiled writer, read back from the profiler's trace: every span is there, on
 the thread the contract names, with each strip's origin, and the bytes on
-the ``d2h``, ``consume`` and ``flush`` spans add up to what moved."""
+the ``d2h``, ``consume`` and ``flush`` spans add up to what moved; each
+``consume`` counts the byte ranges it wrote."""
 import collections
+import json
 import os
 
 import jax
@@ -12,6 +14,7 @@ import pytest
 from repro import pipelines as PP
 from repro.core import ImageInfo, StreamingExecutor, StripeSplitter, whole
 from repro.raster import TiledSource, TileWriter
+from repro.raster.tiled import TILED_HEADER_BYTES, TILED_MAGIC
 
 ROWS, COLS, BANDS, STRIP = 44, 30, 3, 8  # a ragged last strip
 DISPATCH = ("begin", "wait_inputs", "dispatch", "d2h", "wait_write", "end")
@@ -84,4 +87,11 @@ def test_streamed_pass_emits_every_span(tmp_path, prefetch):
         total[n] += st.get("bytes", 0)
     assert total["d2h"] == ROWS * COLS * BANDS * np.dtype(np.uint8).itemsize
     assert total["consume"] + total["flush"] == os.path.getsize(product)
-    assert total["consume"] > 0 and total["flush"] > 0
+    assert total["consume"] > 0
+    # each region went straight to its byte ranges; ``end`` wrote only the
+    # index and the header
+    assert all(st["ranges"] > 0 for n, _, st in spans if n == "consume")
+    with open(product, "rb") as f:
+        head = f.read(TILED_HEADER_BYTES)
+    meta = json.loads(head[len(TILED_MAGIC):].rstrip(b"\0"))
+    assert total["flush"] == meta["index_length"] + TILED_HEADER_BYTES
