@@ -240,7 +240,10 @@ class StreamingExecutor:
         fn = self.plan_cache.compiled_for(
             desc, lambda: self.pipeline.lower_pull(desc)
         )
-        arrays = desc.read_sources()
+        with span("read", row0=region.row0, col0=region.col0) as sp:
+            arrays = desc.read_sources()
+            sp.set_metadata(bytes=sum(a.nbytes for a in arrays),
+                            inputs=len(arrays))
         return desc, fn, arrays
 
     def run(self, keep_outputs: bool = False) -> StreamResult:

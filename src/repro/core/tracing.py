@@ -40,7 +40,11 @@ in one of them or in that small remainder:
 The prefetch threads (with ``prefetch=0``, the dispatching thread, inside
 ``wait_inputs``):
 
-- ``describe`` (``row0``, ``col0``): the describe pass of a strip.
+- ``describe`` (``row0``, ``col0``): the describe pass of a strip;
+- ``read`` (``row0``, ``col0``, ``bytes``, ``inputs``): the strip's source
+  reads (``read_sources``), one array per input at its described window;
+  ``bytes`` are the arrays' bytes, ``inputs`` how many arrays there are.
+  Rows an input's windows share across strip seams count in each strip.
 
 The thread that runs ``TileWriter.consume`` (the write-behind thread, or
 the dispatching thread with ``prefetch=0``) and the one that runs its
@@ -54,7 +58,8 @@ the dispatching thread with ``prefetch=0``) and the one that runs its
   the header it writes.
 
 Over one pass, the ``consume`` and ``flush`` bytes add up to the size of
-the finished file, and the ``d2h`` bytes to the output's bytes.
+the finished file, the ``d2h`` bytes to the output's bytes, and the
+``read`` bytes to the described windows' bytes over every strip.
 """
 from __future__ import annotations
 

@@ -18,14 +18,14 @@ from repro.raster.tiled import TILED_HEADER_BYTES, TILED_MAGIC
 
 ROWS, COLS, BANDS, STRIP = 44, 30, 3, 8  # a ragged last strip
 DISPATCH = ("begin", "wait_inputs", "dispatch", "d2h", "wait_write", "end")
-PER_STRIP = ("describe", "wait_inputs", "dispatch", "d2h", "wait_write", "consume")
+PER_STRIP = ("describe", "read", "wait_inputs", "dispatch", "d2h", "wait_write", "consume")
 
 
-def _stored_scene(path):
-    data = np.random.default_rng(0).integers(0, 4096, (ROWS, COLS, BANDS), np.uint16)
+def _stored_scene(path, rows=ROWS, cols=COLS, bands=BANDS, seed=0):
+    data = np.random.default_rng(seed).integers(0, 4096, (rows, cols, bands), np.uint16)
     w = TileWriter(str(path), tile_rows=16)
-    w.begin(ImageInfo(ROWS, COLS, BANDS, np.uint16))
-    w.consume(whole(ROWS, COLS), data)
+    w.begin(ImageInfo(rows, cols, bands, np.uint16))
+    w.consume(whole(rows, cols), data)
     w.end()
 
 
@@ -60,7 +60,7 @@ def test_streamed_pass_emits_every_span(tmp_path, prefetch):
     spans = _program_spans(tmp_path / "trace")
 
     names = {n for n, _, _ in spans}
-    assert names == set(DISPATCH) | {"describe", "consume", "flush"}
+    assert names == set(DISPATCH) | {"describe", "read", "consume", "flush"}
     origins = collections.defaultdict(set)
     for n, _, st in spans:
         if "row0" in st:
@@ -70,11 +70,12 @@ def test_streamed_pass_emits_every_span(tmp_path, prefetch):
         assert origins[n] == want, n
 
     # the dispatching thread's spans share one line; with prefetch the
-    # describe pass and the writes run on lines of their own
+    # describe pass, the reads and the writes run on lines of their own
     lines = collections.defaultdict(set)
     for n, line, _ in spans:
         lines[n].add(line)
     (dispatch,) = set().union(*(lines[n] for n in DISPATCH))
+    assert lines["read"] == lines["describe"]
     if prefetch:
         assert dispatch not in lines["describe"] | lines["consume"]
     else:
@@ -95,3 +96,51 @@ def test_streamed_pass_emits_every_span(tmp_path, prefetch):
         head = f.read(TILED_HEADER_BYTES)
     meta = json.loads(head[len(TILED_MAGIC):].rstrip(b"\0"))
     assert total["flush"] == meta["index_length"] + TILED_HEADER_BYTES
+
+
+def _described_bytes(p, m, region):
+    """Bytes of the windows the describe pass asks of each input for
+    ``region``: what the strip's read stage must deliver."""
+    desc = p.describe_pull(m, region, virtual=p.virtual_describe_mode())
+    wins = desc.windows or (None,) * len(desc.reads)
+    total = 0
+    for (src, _, req), w in zip(desc.reads, wins):
+        info = p.info(src)
+        rows, cols = w or (req.rows, req.cols)
+        total += rows * cols * info.bands * np.dtype(info.dtype).itemsize
+    return total
+
+
+@pytest.mark.parametrize("job", ["P3", "P6"])
+def test_read_spans_count_each_strip_inputs(tmp_path, job):
+    """``read`` carries one strip's inputs: two arrays for P3 (XS and PAN,
+    two stored files on two grids), one for P6; its bytes add up to the
+    described windows' bytes over the strips.  For P3 that is more than the
+    stored rasters hold: the XS rows (and PAN halo rows) a strip's footprint
+    shares with its neighbours are read again at every seam."""
+    if job == "P3":
+        _stored_scene(tmp_path / "xs.rtic", 10, 13, 4, seed=1)
+        _stored_scene(tmp_path / "pan.rtic", 40, 52, 1, seed=2)
+        srcs = [TiledSource(str(tmp_path / n)) for n in ("xs.rtic", "pan.rtic")]
+        p, m = PP.p3_pansharpening(*srcs, use_pallas=False)
+    else:
+        _stored_scene(tmp_path / "scene.rtic")
+        srcs = [TiledSource(str(tmp_path / "scene.rtic"))]
+        p, m = PP.p6_conversion(*srcs)
+    try:
+        ex = StreamingExecutor(p, m, StripeSplitter(stripe_rows=STRIP))
+        strips = ex.my_regions()
+        with jax.profiler.trace(str(tmp_path / "trace")):
+            ex.run()
+    finally:
+        for s in srcs:
+            s.close()
+    reads = [st for n, _, st in _program_spans(tmp_path / "trace") if n == "read"]
+    assert sorted((st["row0"], st["col0"]) for st in reads) == sorted(
+        (r.row0, r.col0) for r in strips)
+    assert {st["inputs"] for st in reads} == {len(srcs)}
+    want = sum(_described_bytes(p, m, r) for r in strips)
+    assert sum(st["bytes"] for st in reads) == want
+    stored = sum(s.info().rows * s.info().cols * s.info().bands * 2 for s in srcs)
+    if job == "P3":
+        assert want > stored
