@@ -9,10 +9,11 @@
                                      # against one-chip streaming, bit for bit
 
 Runs through the user entry point ``repro.pipelines.run_pipeline``.  It
-fails before any work unless JAX's first device is a TPU, and fails when a
-kernel phase's plan did not take the compiled Pallas kernel.  Any failed
-check exits non-zero.  The last line of standard output is one JSON object
-naming the device; the lines above it are smoke timings (one cold run with
+fails before any work unless JAX's first device is a TPU, fails when a
+kernel phase's plan did not take the compiled Pallas kernel, and fails when
+a streamed strip reaches the sink as anything but an array of the sink's
+dtype with C-ordered rows.  Any failed check exits non-zero.  The last line
+of standard output is one JSON object naming the device; the lines above it are smoke timings (one cold run with
 compiles, one warm run), not benchmark metrics.
 """
 from __future__ import annotations
@@ -70,6 +71,26 @@ def compare(name: str, got: np.ndarray, want: np.ndarray, tol) -> float:
     return err
 
 
+def guard_layout(name: str, pair) -> None:
+    """Check every strip that reaches the sink: a host array in the sink's
+    dtype whose rows are C-ordered, which the writer stores from views.  A
+    strip in the TPU's own layout would pass every CPU test and cost the
+    writer a host interleave."""
+    p, m = pair
+    dtype = np.dtype(p.info(m).dtype)
+    consume = m.consume
+
+    def checked(region, data):
+        ok = isinstance(data, np.ndarray) and data[0].flags.c_contiguous
+        check(ok and data.dtype == dtype,
+              f"{name}: the strip at {region.index} reached the sink as a "
+              f"{type(data).__name__} of {data.dtype}, not an array of "
+              f"{dtype} with C-ordered rows")
+        consume(region, data)
+
+    m.consume = checked
+
+
 def stream(pair, cache, splitter):
     from repro import pipelines as PP
 
@@ -103,6 +124,7 @@ def kernel_phase(name: str, build, scene_shape, splitter) -> None:
     desc = p.describe_pull(m, first, virtual=p.virtual_describe_mode() or False)
     check(bool(desc.pallas_nodes), f"{name}: the plan did not take the Pallas kernel")
 
+    guard_layout(name, pair)
     cache = PlanCache()
     res, cold = stream(pair, cache, splitter)
     out = m.result
@@ -130,6 +152,7 @@ def conversion_phase(scene, splitter, workdir: str) -> None:
 
     path = os.path.join(workdir, "p6.rtic")
     pair = PP.p6_conversion(scene, mapper_factory=lambda: as_sink(path))
+    guard_layout("P6", pair)
     cache = PlanCache()
     res, cold = stream(pair, cache, splitter)
     res, warm = stream(pair, cache, splitter)

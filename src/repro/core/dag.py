@@ -43,7 +43,11 @@ for pool consumers and never for stage-granularity SPMD consumers).  A
 blocked producer therefore always implies a consumer that is processing
 ready regions and will release capacity, and a blocked consumer either
 drains committed/offered rows via flush or lifts the producer past the
-bound — there is no cycle.  Waits additionally poll with a short timeout as
+bound — there is no cycle.  A producer with several outgoing edges
+(:class:`EdgeFanout`) admits each strip to all of them at once, and proceeds
+while any of them has unmet demand: a strip offered on one edge but held
+back by another would be neither written nor counted as unmet.  Waits
+additionally poll with a short timeout as
 a belt-and-braces guard, and every failure path wakes all sleepers.
 
 When the producer offers strips in consumer (row) order — the pipelined
@@ -205,34 +209,59 @@ class EdgeQueue:
         """
         with self._cv:
             self._raise_if_failed_locked()
-            if region.col0 != 0:
-                raise ValueError(
-                    f"edge {self.producer!r}→{self.consumer!r}: pipelined "
-                    "producers must write full-width strips (row-granularity "
-                    "commit protocol); got a tile split — use barrier mode "
-                    "or a stripe splitter"
-                )
-            self.stats.offers += 1
-            while (
-                self._consumer_active
-                and not self._consumer_done
-                and len(self._tokens) >= self.capacity
-                and not self._unmet_demand_locked()
-            ):
+            while self._full_locked() and not self._unmet_demand_locked():
                 self._cv.wait(_POLL_S)
                 self._raise_if_failed_locked()
-            if (
-                self._consumer_active
-                and not self._consumer_done
-                and len(self._tokens) >= self.capacity
-            ):
-                self.stats.overdrafts += 1
-            self._tokens.append((region.row0, region.row1))
-            self._offered.add(region.row0, region.row1)
-            self.stats.max_in_flight = max(
-                self.stats.max_in_flight, len(self._tokens)
+            self._admit_locked(region)
+
+    def full(self) -> bool:
+        """``capacity`` strips are in flight to a region-granular consumer
+        that is still running.  Raises when the run was cancelled."""
+        with self._cv:
+            self._raise_if_failed_locked()
+            return self._full_locked()
+
+    def starved(self) -> bool:
+        """A blocked consumer demands rows no offered strip covers."""
+        with self._cv:
+            return self._unmet_demand_locked()
+
+    def wait_change(self) -> None:
+        """Sleep until this edge's state changes, at most one poll period."""
+        with self._cv:
+            self._cv.wait(_POLL_S)
+
+    def admit(self, region: ImageRegion) -> None:
+        """Put ``region`` in flight without waiting (the caller applied the
+        flow control: :meth:`EdgeFanout.offer`)."""
+        with self._cv:
+            self._raise_if_failed_locked()
+            self._admit_locked(region)
+
+    def _full_locked(self) -> bool:
+        return (
+            self._consumer_active
+            and not self._consumer_done
+            and len(self._tokens) >= self.capacity
+        )
+
+    def _admit_locked(self, region: ImageRegion) -> None:
+        if region.col0 != 0:
+            raise ValueError(
+                f"edge {self.producer!r}→{self.consumer!r}: pipelined "
+                "producers must write full-width strips (row-granularity "
+                "commit protocol); got a tile split — use barrier mode "
+                "or a stripe splitter"
             )
-            self._cv.notify_all()  # waiters re-check offered coverage
+        self.stats.offers += 1
+        if self._full_locked():
+            self.stats.overdrafts += 1
+        self._tokens.append((region.row0, region.row1))
+        self._offered.add(region.row0, region.row1)
+        self.stats.max_in_flight = max(
+            self.stats.max_in_flight, len(self._tokens)
+        )
+        self._cv.notify_all()  # waiters re-check offered coverage
 
     def commit(self, row0: int, row1: int) -> None:
         """Rows ``[row0, row1)`` are on disk (called post-``pwrite``/flush by
@@ -388,8 +417,19 @@ class EdgeFanout:
             e.set_flush(cb)
 
     def offer(self, region: ImageRegion) -> None:
+        """Admit ``region`` to every edge at once, when no edge is at
+        capacity or some edge's consumer waits on rows no offered strip
+        covers.  Offered edge by edge, its rows would read as offered on one
+        edge while another edge held the write back, and a consumer of the
+        first that waits behind a consumer of the second would wait for
+        ever."""
+        while True:
+            full = [e for e in self.edges if e.full()]
+            if not full or any(e.starved() for e in self.edges):
+                break
+            full[0].wait_change()
         for e in self.edges:
-            e.offer(region)
+            e.admit(region)
 
     def commit(self, row0: int, row1: int) -> None:
         for e in self.edges:

@@ -121,10 +121,12 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import math
 import threading
 from typing import TYPE_CHECKING, Callable, Dict, List, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.process_object import boundary_pad
@@ -277,31 +279,81 @@ class PlanDescription:
         return {p.name: p.reset() for p in self.persistent_nodes}
 
 
+#: lanes in a TPU vector register
+_LANES = 128
+
+
+def _lane_dense(out):
+    """A ``(rows, cols, ...)`` pixel output as a 2-D ``(rows, cols' *
+    pixel)`` array of whole pixels, ``cols' >= cols`` the fewest columns
+    whose row fills whole 128-lane vectors (the added columns are zeros).
+    A TPU lays such an output out row-major, so it reaches the host
+    C-ordered row by row; a rank-3 strip, or a 2-D one whose row ends
+    inside a vector, it lays out rows fastest.  Padding the columns costs
+    the compiler nothing; relayouting the strip to one flat row instead
+    takes it seconds to minutes a strip shape."""
+    pixel = math.prod(out.shape[2:])
+    pad = -out.shape[1] % (_LANES // math.gcd(pixel, _LANES))
+    if pad:
+        out = jnp.pad(out, [(0, 0), (0, pad)] + [(0, 0)] * (out.ndim - 2))
+    return out.reshape(out.shape[0], -1)
+
+
 class _CompiledEntry:
     """One jitted canonical function.  The first call is serialized so
     concurrent pool workers can't race XLA into tracing the same signature
     twice; afterwards calls are lock-free.  ``canonical_fn`` stays reachable
     so the SPMD executor can trace the very same closure into its shard_map
-    program instead of rebuilding it."""
+    program instead of rebuilding it.
+
+    The program hands its pixel output back lane-dense (see
+    :func:`_lane_dense`), pixel-interleaved in row-major order;
+    :meth:`to_host` views the host array back to the canonical output's
+    shape at no cost, each row C-ordered, rows a padded row apart."""
 
     def __init__(self, canonical_fn: Callable, stats: CacheStats):
         self.canonical_fn = canonical_fn
+        #: lane-dense output shape -> the canonical output shape it holds
+        self._views: Dict[Tuple[int, ...], Tuple[int, ...]] = {}
 
-        def counted(arrays, pstates, origins):
+        def lane_dense(arrays, pstates, origins):
             stats.compiles += 1  # executes at trace time only
-            return canonical_fn(arrays, pstates, origins)
+            out, new = canonical_fn(arrays, pstates, origins)
+            if out.ndim > 1:
+                dense = _lane_dense(out)
+                if dense.shape != out.shape:
+                    self._views[dense.shape] = out.shape
+                out = dense
+            return out, new
 
-        self._jitted = jax.jit(counted)
+        self._jitted = jax.jit(lane_dense)
         self._lock = threading.Lock()
         self._primed = False
 
-    def __call__(self, arrays, pstates, origins):
+    def launch(self, arrays, pstates, origins):
+        """Enqueue the program: its lane-dense pixel output, on the device,
+        and the new persistent states."""
         if not self._primed:
             with self._lock:
                 out = self._jitted(arrays, pstates, origins)
                 self._primed = True
                 return out
         return self._jitted(arrays, pstates, origins)
+
+    def to_host(self, out) -> np.ndarray:
+        """A launched pixel output on the host, in the canonical output's
+        shape: a view of the lane-dense array without its padding."""
+        host = np.asarray(out)
+        shape = self._views.get(host.shape)
+        if shape is None:
+            return host
+        return host[:, : math.prod(shape[1:])].reshape(shape)
+
+    def host(self, arrays, pstates, origins) -> Tuple[np.ndarray, Dict]:
+        """Run the program: the pixels as :meth:`to_host` gives them, and
+        the new persistent states."""
+        out, pstates = self.launch(arrays, pstates, origins)
+        return self.to_host(out), pstates
 
 
 class PlanCache:
@@ -417,7 +469,7 @@ class PlanCache:
             seen.add(desc.signature)
             entry = self.compiled_for(desc, lambda: pipeline.lower_pull(desc))
             if execute:
-                out, _ = entry(
+                out, _ = entry.launch(
                     desc.read_sources(), desc.initial_pstates(), desc.origins()
                 )
                 jax.block_until_ready(out)

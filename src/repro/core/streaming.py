@@ -280,15 +280,17 @@ class StreamingExecutor:
             # the device's own time is busy, not idle: inside ``dispatch``
             # only the program's launch and completion latency read as idle
             with span("dispatch", row0=r.row0, col0=r.col0):
-                out, pstates = fn(arrays, pstates, plan.origins())
+                out, pstates = fn.launch(arrays, pstates, plan.origins())
                 if self.region_gate is not None:
                     # pacing-only release (the data lives on disk): fire once
                     # the region's pixels are produced and handed to the
                     # write stage
                     self.region_gate.done(plan)
                 jax.block_until_ready(out)
-            with span("d2h", row0=r.row0, col0=r.col0, bytes=out.nbytes):
-                return np.asarray(out)
+            with span("d2h", row0=r.row0, col0=r.col0) as sp:
+                data = fn.to_host(out)
+                sp.set_metadata(bytes=data.nbytes)
+                return data
 
         def produce_sync(region: ImageRegion) -> np.ndarray:
             if compiled_path:
@@ -508,8 +510,9 @@ def run_pool(
                     region_gate.wait(desc)  # block until input rows commit
             if use_jit:
                 fn = cache.compiled_for(desc, lambda: pipeline.lower_pull(desc))
-                out, pstates = fn(desc.read_sources(), pstates, desc.origins())
-                data = np.asarray(out)
+                data, pstates = fn.host(
+                    desc.read_sources(), pstates, desc.origins()
+                )
             else:
                 data = np.asarray(
                     pipeline.pull(mapper, region, persistent_hook=hook)
@@ -684,8 +687,8 @@ class BatchedRegionPuller:
         """Unbatched single-region pull through the registry (the per-tile
         oracle the serving-diff compares the batched path against)."""
         desc = self.describe(region)
-        out, _ = self._entry(desc)(self._read(desc), {}, desc.origins())
-        return np.asarray(out)
+        out, _ = self._entry(desc).host(self._read(desc), {}, desc.origins())
+        return out
 
     def _chunks(self, n: int) -> List[int]:
         """Decompose a group of ``n`` tiles into bucket-sized chunks, peeling
@@ -717,8 +720,8 @@ class BatchedRegionPuller:
             return []
         if len(descs) == 1:
             d = descs[0]
-            out, _ = self._entry(d)(self._read(d), {}, d.origins())
-            return [np.asarray(out)]
+            out, _ = self._entry(d).host(self._read(d), {}, d.origins())
+            return [out]
         sizes = self._chunks(len(descs))
         if len(sizes) > 1:
             out: List[np.ndarray] = []

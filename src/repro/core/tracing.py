@@ -30,6 +30,8 @@ in one of them or in that small remainder:
   and what idles there is the program's launch and completion latency;
 - ``d2h`` (``row0``, ``col0``, ``bytes``): pulling a finished strip's
   output from the device to the host, after the device has finished it;
+  ``bytes`` are the strip's pixel bytes (not the lane padding its rows
+  travel with);
 - ``wait_write`` (``row0``, ``col0``): handing a strip to the write stage,
   blocked while the write-behind queue is full, or, with ``prefetch=0``,
   the inline ``mapper.consume``; without an origin, the drain of the
@@ -50,10 +52,12 @@ The thread that runs ``TileWriter.consume`` (the write-behind thread, or
 the dispatching thread with ``prefetch=0``) and the one that runs its
 ``end``:
 
-- ``consume`` (``row0``, ``col0``, ``bytes``, ``ranges``): one region
-  written straight to its tiles' byte ranges at every pyramid level;
-  ``bytes`` are the pixel bytes written, ``ranges`` the byte ranges, one
-  per ``pwrite``/``pwritev`` call;
+- ``consume`` (``row0``, ``col0``, ``bytes``, ``ranges``, ``interleaved``):
+  one region written straight to its tiles' byte ranges at every pyramid
+  level; ``bytes`` are the pixel bytes written, ``ranges`` the byte ranges,
+  one per ``pwrite``/``pwritev`` call, ``interleaved`` the level-0 bytes
+  the writer had to interleave on the host first (0 when the region's rows
+  came C-ordered in the file's dtype);
 - ``flush`` (``bytes``): ``TileWriter.end``; ``bytes`` are the index and
   the header it writes.
 

@@ -446,8 +446,9 @@ class TileWriter(Mapper, RasterSink):
     consumed region is then written straight to its final ranges: the rows
     of its level-0 pixels, and its decimated pixels at every overview level
     (gathered one whole pixel at a time), from views of the region's data
-    (data in another layout, such as a strip pulled from a device, is first
-    interleaved into a buffer the consuming thread keeps).
+    (data whose rows are not C-ordered, or in another dtype, is first
+    interleaved into a buffer the consuming thread keeps; the streaming
+    executor's strips come with C-ordered rows).
     Regions need not align with the tile grid; any disjoint cover, in any
     order and from several threads at once, gives the same file, and pixels
     no region covered read as zero.  ``end`` writes the footer index after
@@ -519,18 +520,18 @@ class TileWriter(Mapper, RasterSink):
 
     def consume(self, out_region: ImageRegion, data: np.ndarray) -> None:
         with span("consume", row0=out_region.row0, col0=out_region.col0) as sp:
-            written, ranges = self._consume(out_region, data)
-            sp.set_metadata(bytes=written, ranges=ranges)
+            written, ranges, interleaved = self._consume(out_region, data)
+            sp.set_metadata(bytes=written, ranges=ranges, interleaved=interleaved)
 
     def _consume(
         self, out_region: ImageRegion, data: np.ndarray
-    ) -> Tuple[int, int]:
+    ) -> Tuple[int, int, int]:
         """Write one region's pixels at every level; returns the bytes and
-        the byte ranges written."""
+        the byte ranges written, and the bytes interleaved first."""
         full = self._info.full_region
         if not full.contains(out_region):
             raise ValueError(f"consume {out_region} outside image {full}")
-        pix = self._pixels(out_region, data)
+        pix, interleaved = self._pixels(out_region, data)
         region = out_region
         written = ranges = 0
         for lv in range(len(self._dims)):
@@ -549,17 +550,24 @@ class TileWriter(Mapper, RasterSink):
             n, k = self._write_level(lv, region, pix)
             written += n
             ranges += k
-        return written, ranges
+        return written, ranges, interleaved
 
-    def _pixels(self, region: ImageRegion, data: np.ndarray) -> np.ndarray:
-        """The region's pixels as a C-ordered ``(rows, cols)`` array of whole
-        pixels.  A strip pulled from a device comes in the device's layout
-        (rows or bands fastest, not bands); it is interleaved a block of
-        columns and one band at a time, which keeps numpy's strided copy in
-        cache, into a buffer the calling thread keeps, so its pages are
-        touched once and not for every region."""
+    def _pixels(
+        self, region: ImageRegion, data: np.ndarray
+    ) -> Tuple[np.ndarray, int]:
+        """The region's pixels as a ``(rows, cols)`` array of whole pixels,
+        each row C-ordered (rows may lie further apart, as in a strip the
+        plan entry hands back), and the bytes interleaved to make it: none
+        for such a region in the file's dtype.  Data in another layout (a
+        rank-3 array pulled from a TPU comes rows fastest) or dtype is
+        interleaved a block of columns and one band at a time, which keeps
+        numpy's strided copy in cache, into a buffer the calling thread
+        keeps, so its pages are touched once and not for every region."""
         arr = np.asarray(data).reshape(region.rows, region.cols, self._info.bands)
-        if arr.dtype != self._dtype or not arr.flags.c_contiguous:
+        interleaved = 0
+        rows_apart = arr.strides[0] >= arr[0].nbytes  # in order, disjoint
+        if (arr.dtype != self._dtype or not arr[0].flags.c_contiguous
+                or not rows_apart):
             buf = getattr(self._local, "buf", None)
             if buf is None or buf.size < arr.size:
                 buf = self._local.buf = np.empty(arr.size, self._dtype)
@@ -569,7 +577,8 @@ class TileWriter(Mapper, RasterSink):
                 for b in range(arr.shape[2]):
                     out[:, cols, b] = arr[:, cols, b]
             arr = out
-        return arr.view(self._pixel).reshape(region.rows, region.cols)
+            interleaved = arr.nbytes
+        return arr.view(self._pixel).reshape(region.rows, region.cols), interleaved
 
     def _write_level(
         self, lv: int, region: ImageRegion, pix: np.ndarray
@@ -577,8 +586,12 @@ class TileWriter(Mapper, RasterSink):
         """Write ``pix``, the pixels of ``region`` of level ``lv``, into that
         level's tiles; returns the bytes and the byte ranges written."""
         px = self._pixel.itemsize
-        src = memoryview(pix.reshape(-1).view(np.uint8))
-        stride = region.cols * px
+        stride = pix.strides[0]
+        # the bytes from the first pixel to the last, rows ``stride`` apart
+        src = memoryview(np.lib.stride_tricks.as_strided(
+            pix.view(np.uint8), ((region.rows - 1) * stride + region.cols * px,),
+            (1,), writeable=False,
+        ))
         tiles = self._index[lv]
         written = ranges = 0
         for ty, tx, tile in tile_cover(

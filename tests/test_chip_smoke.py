@@ -52,3 +52,27 @@ def test_kernel_phase_fails_off_the_kernel_plan(chip_smoke, monkeypatch):
             "P5", lambda up: PP.p5_meanshift(scene, use_pallas=up),
             (16, 128, 4), StripeSplitter(stripe_rows=8),
         )
+
+
+def test_guard_layout_refuses_a_strip_in_another_layout(chip_smoke):
+    """The sink-side guard passes a strip in the sink's dtype with C-ordered
+    rows, also rows a padded row apart as the plan entry hands them, and
+    refuses a columns-major one or one in another dtype."""
+    import numpy as np
+
+    from repro import pipelines as PP
+    from repro.core import whole
+    from repro.raster import SyntheticScene
+
+    p, m = pair = PP.p6_conversion(SyntheticScene(8, 6, bands=3))
+    chip_smoke.guard_layout("P6", pair)
+    m.begin(p.info(m))
+    strip = np.arange(8 * 6 * 3, dtype=np.uint8).reshape(8, 6, 3)
+    padded = np.zeros((8, 128), np.uint8)
+    padded[:, : 6 * 3] = strip.reshape(8, -1)
+    for good in (strip, padded[:, : 6 * 3].reshape(8, 6, 3)):
+        m.consume(whole(8, 6), good)
+        np.testing.assert_array_equal(m.result, strip)
+    for bad in (np.asfortranarray(strip), strip.astype(np.float32)):
+        with pytest.raises(chip_smoke.SmokeFailure, match="reached the sink"):
+            m.consume(whole(8, 6), bad)

@@ -10,7 +10,10 @@ devices vs the eager oracle) lives in tests/test_cross_executor_diff.py.
 """
 import threading
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro import pipelines as PP
 from repro.core import (
@@ -22,6 +25,7 @@ from repro.core import (
     global_plan_cache,
     run_pool,
 )
+from repro.core.execplan import CacheStats, _CompiledEntry
 from repro.filters import BandStatistics, gaussian_smoothing
 from repro.raster import MemoryMapper, SyntheticScene, make_spot6_pair
 
@@ -84,6 +88,43 @@ def test_streaming_executor_lowers_once_per_signature():
     ).run()
     assert cache.stats.lowers == cache.stats.compiles == 1
     assert cache.stats.hits == 7
+
+
+@pytest.mark.parametrize("dtype", [np.uint8, np.float32])
+@pytest.mark.parametrize("bands", [1, 4, 5])
+def test_host_call_hands_back_c_ordered_pixels(bands, dtype):
+    """The entry's host call gives the strip as a ``(rows, cols, bands)``
+    host array equal to the canonical program's output, with equal
+    persistent states, from one compiled program: a view of the lane-dense
+    output, each row C-ordered and a whole number of 128-lane vectors long,
+    so a sink writes it from views.  On the CPU every array is row-major,
+    so this guards the contract, not a TPU's layout: ``chip_smoke.py``
+    checks that on the chip."""
+    rows, cols = 6, 7
+
+    def canonical(arrays, pstates, origins):
+        (x,) = arrays
+        # band-planar blocks put back band-last, as a kernel's output is
+        planar = jnp.stack([x[..., b] + origins[0] for b in range(bands)])
+        out = jnp.moveaxis(planar, 0, -1).astype(dtype)
+        return out, {"acc": pstates["acc"] + x.sum()}
+
+    stats = CacheStats()
+    entry = _CompiledEntry(canonical, stats)
+    x = np.random.default_rng(bands).integers(0, 200, (rows, cols, bands))
+    args = ([x.astype(np.float32)], {"acc": jnp.float32(1)}, (jnp.int32(3),))
+    got, got_states = entry.host(*args)
+    want, want_states = jax.jit(canonical)(*args)
+    item = np.dtype(dtype).itemsize
+    assert isinstance(got, np.ndarray) and got[0].flags.c_contiguous
+    assert got.strides[1:] == (bands * item, item)
+    assert got.strides[0] % (128 * item) == 0
+    assert got.shape == want.shape == (rows, cols, bands)
+    assert got.dtype == want.dtype == dtype
+    np.testing.assert_array_equal(got, np.asarray(want))
+    np.testing.assert_array_equal(got, (x + 3).astype(dtype))
+    assert float(got_states["acc"]) == float(want_states["acc"]) == 1 + x.sum()
+    assert stats.compiles == 1
 
 
 def test_global_plan_cache_is_process_wide():

@@ -445,6 +445,33 @@ def test_edge_queue_rejects_tile_offers_and_bad_capacity():
         q.offer(ImageRegion((0, 4), (4, 4)))
 
 
+def test_fanout_admits_a_strip_a_consumer_of_another_edge_waits_on():
+    """A producer with two outgoing edges, one at capacity: while the other
+    edge's consumer waits on the next strip's rows, the strip is admitted to
+    both edges (an overdraft on the full one) and the wait ends once it is
+    committed; admitted edge by edge, the offer blocked for ever."""
+    from repro.core.dag import EdgeFanout
+
+    a, b = EdgeQueue("p", "a", capacity=1), EdgeQueue("p", "b", capacity=1)
+    fan = EdgeFanout([a, b])
+    fan.opened(type("Info", (), {"rows": 8})())
+    for e in (a, b):
+        e.consumer_started()
+    b.offer(ImageRegion((0, 0), (4, 4)))  # b at capacity, its consumer busy
+    waiter = threading.Thread(target=a.wait_rows, args=(4, 8), daemon=True)
+    waiter.start()
+    offer = threading.Thread(
+        target=fan.offer, args=(ImageRegion((4, 0), (4, 4)),), daemon=True
+    )
+    offer.start()
+    offer.join(5)
+    assert not offer.is_alive(), "the fanout's offer wedged"
+    assert (a.stats.offers, b.stats.offers, b.stats.overdrafts) == (1, 2, 1)
+    fan.commit(4, 8)
+    waiter.join(5)
+    assert not waiter.is_alive()
+
+
 def test_edge_queue_wait_rows_detects_missing_commit_hook():
     q = EdgeQueue("p", "c", capacity=1)
     q.open(8)

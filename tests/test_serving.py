@@ -354,7 +354,7 @@ def test_global_plan_cache_concurrent_serving_storm():
                     d = p.describe_pull(m, ImageRegion((16 * (i % 2), 0), (16, 32)))
                     entry = cache.compiled_for(d, lower_a)
                     if i % 10 == 0:  # exercise the compiled fn concurrently
-                        entry(d.read_sources(), d.initial_pstates(), d.origins())
+                        entry.host(d.read_sources(), d.initial_pstates(), d.origins())
                     cache.stats_snapshot()
             except Exception as e:  # pragma: no cover — surfaced below
                 errors.append(e)
@@ -363,7 +363,7 @@ def test_global_plan_cache_concurrent_serving_storm():
             try:
                 barrier.wait(timeout=30)
                 entry = cache.compiled_for(desc_b, lower_b)
-                entry(desc_b.read_sources(), desc_b.initial_pstates(), desc_b.origins())
+                entry.host(desc_b.read_sources(), desc_b.initial_pstates(), desc_b.origins())
             except Exception as e:  # pragma: no cover
                 errors.append(e)
 

@@ -236,7 +236,7 @@ def _cover(kind, rows, cols, tile_r):
     full = whole(rows, cols)
     if kind == "whole":
         return [full]
-    if kind in ("strips", "device", "threads"):  # full width, off the grid
+    if kind in ("strips", "device", "padded", "flipped", "threads"):  # full width
         h = tile_r + 3 if kind != "threads" else max(1, tile_r // 2 + 1)
         return [ImageRegion((r, 0), (min(h, rows - r), cols))
                 for r in range(0, rows, h)]
@@ -245,8 +245,17 @@ def _cover(kind, rows, cols, tile_r):
     return blocks
 
 
+def _padded(a):
+    """``a`` with its rows a padded row apart, as the plan entry hands a
+    strip to the host."""
+    rows, cols, bands = a.shape
+    buf = np.zeros((rows, (cols + 3) * bands), a.dtype)
+    buf[:, : cols * bands] = a.reshape(rows, -1)
+    return buf[:, : cols * bands].reshape(a.shape)
+
+
 @pytest.mark.parametrize(
-    "cover", ["whole", "strips", "device", "blocks", "threads"]
+    "cover", ["whole", "strips", "device", "padded", "flipped", "blocks", "threads"]
 )
 @pytest.mark.parametrize(
     "rows,cols,tile_r,tile_c,bands,dtype,levels",
@@ -264,14 +273,18 @@ def test_file_bytes_match_oracle_for_every_cover(
 ):
     """Any disjoint cover, in any order or from several threads at once,
     gives the oracle's file byte for byte.  ``device`` and ``threads`` hand
-    over column-major strips, the layout a TPU's strips come back in."""
+    over column-major strips, the layout a TPU's rank-3 strips come back
+    in; ``padded`` strips with C-ordered rows a padded row apart;
+    ``flipped`` strips whose rows run backwards in memory."""
     data = _rand(rows, cols, bands, dtype=dtype, seed=rows + cols + bands)
     geo = GeoTransform(1.0, 2.0, 6.0, -6.0)
     path = str(tmp_path / "c.rtic")
     w = TileWriter(path, tile_r, tile_c, levels=levels)
     w.begin(ImageInfo(rows, cols, bands, data.dtype, geo))
     regions = _cover(cover, rows, cols, tile_r)
-    layout = np.asfortranarray if cover in ("device", "threads") else np.asarray
+    layout = {"device": np.asfortranarray, "threads": np.asfortranarray,
+              "padded": _padded, "flipped": lambda a: a[::-1].copy()[::-1]
+              }.get(cover, np.asarray)
     if cover == "threads":
         with ThreadPoolExecutor(4) as pool:
             list(pool.map(

@@ -103,3 +103,39 @@ def test_pansharpen_compiles_for_v5e(one_chip, raw):
         ((STRIP + 2 * r, SPOT6_PAN_COLS + 2 * r, 1), raw),
     )
     _assert_kernel(compiled, "pansharpen_rcs")
+
+
+@pytest.mark.parametrize("bands", [4, 5])
+def test_strip_program_hands_pixels_back_lane_dense_for_v5e(one_chip, bands):
+    """The plan entry's program for a P6 strip of a Sentinel-2 tile width
+    returns its pixels as a 2-D ``(rows, cols' * bands)`` array, ``cols'``
+    the columns padded to whole 128-lane rows, which the TPU lays out
+    row-major (a rank-3 strip, or an unpadded 2-D one, it lays out rows
+    fastest), and holds no more temporaries than the canonical program's
+    plus the output (a relayout to one flat row held a lane-padded copy of
+    the strip and took minutes to compile)."""
+    import numpy as np
+
+    from repro import pipelines as PP
+    from repro.core import ImageRegion
+    from repro.core.execplan import CacheStats, _CompiledEntry
+    from repro.raster.sources import ArraySource
+
+    scene = ArraySource(np.broadcast_to(np.zeros((), np.uint16), (3 * STRIP, S2_COLS, bands)))
+    p, m = PP.p6_conversion(scene)
+    desc = p.describe_pull(m, ImageRegion((STRIP, 0), (STRIP, S2_COLS)))
+    plan = p.lower_pull(desc)
+    args = (
+        [jax.ShapeDtypeStruct((STRIP, S2_COLS, bands), jnp.uint16, sharding=one_chip)],
+        {}, tuple(jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+                  for _ in desc.origin_values),
+    )
+    entry = _CompiledEntry(plan.canonical_fn, CacheStats())
+    dense = entry._jitted.lower(*args).compile()
+    canonical = jax.jit(plan.canonical_fn).lower(*args).compile()
+    out_bytes = STRIP * 11008 * bands  # 10980 columns padded to 11008
+    assert canonical.output_formats[0].layout.major_to_minor != (0, 1, 2)
+    assert dense.output_formats[0].layout.major_to_minor == (0, 1)
+    assert dense.memory_analysis().output_size_in_bytes == out_bytes
+    assert (dense.memory_analysis().temp_size_in_bytes
+            <= canonical.memory_analysis().temp_size_in_bytes + out_bytes)

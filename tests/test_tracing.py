@@ -2,7 +2,7 @@
 tiled writer, read back from the profiler's trace: every span is there, on
 the thread the contract names, with each strip's origin, and the bytes on
 the ``d2h``, ``consume`` and ``flush`` spans add up to what moved; each
-``consume`` counts the byte ranges it wrote."""
+``consume`` counts the byte ranges it wrote and the bytes it interleaved."""
 import collections
 import json
 import os
@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from repro import pipelines as PP
-from repro.core import ImageInfo, StreamingExecutor, StripeSplitter, whole
+from repro.core import ImageInfo, PlanCache, StreamingExecutor, StripeSplitter, whole
 from repro.raster import TiledSource, TileWriter
 from repro.raster.tiled import TILED_HEADER_BYTES, TILED_MAGIC
 
@@ -111,6 +111,20 @@ def _described_bytes(p, m, region):
     return total
 
 
+def _stored_job(tmp_path, job, mapper_factory=None):
+    """P3 on two stored files (XS and PAN, two grids) or P6 on one: the
+    stored sources and the built (pipeline, mapper) pair."""
+    if job == "P3":
+        _stored_scene(tmp_path / "xs.rtic", 10, 13, 4, seed=1)
+        _stored_scene(tmp_path / "pan.rtic", 40, 52, 1, seed=2)
+        srcs = [TiledSource(str(tmp_path / n)) for n in ("xs.rtic", "pan.rtic")]
+        return srcs, PP.p3_pansharpening(*srcs, use_pallas=False,
+                                         mapper_factory=mapper_factory)
+    _stored_scene(tmp_path / "scene.rtic")
+    srcs = [TiledSource(str(tmp_path / "scene.rtic"))]
+    return srcs, PP.p6_conversion(*srcs, mapper_factory=mapper_factory)
+
+
 @pytest.mark.parametrize("job", ["P3", "P6"])
 def test_read_spans_count_each_strip_inputs(tmp_path, job):
     """``read`` carries one strip's inputs: two arrays for P3 (XS and PAN,
@@ -118,15 +132,7 @@ def test_read_spans_count_each_strip_inputs(tmp_path, job):
     described windows' bytes over the strips.  For P3 that is more than the
     stored rasters hold: the XS rows (and PAN halo rows) a strip's footprint
     shares with its neighbours are read again at every seam."""
-    if job == "P3":
-        _stored_scene(tmp_path / "xs.rtic", 10, 13, 4, seed=1)
-        _stored_scene(tmp_path / "pan.rtic", 40, 52, 1, seed=2)
-        srcs = [TiledSource(str(tmp_path / n)) for n in ("xs.rtic", "pan.rtic")]
-        p, m = PP.p3_pansharpening(*srcs, use_pallas=False)
-    else:
-        _stored_scene(tmp_path / "scene.rtic")
-        srcs = [TiledSource(str(tmp_path / "scene.rtic"))]
-        p, m = PP.p6_conversion(*srcs)
+    srcs, (p, m) = _stored_job(tmp_path, job)
     try:
         ex = StreamingExecutor(p, m, StripeSplitter(stripe_rows=STRIP))
         strips = ex.my_regions()
@@ -144,3 +150,70 @@ def test_read_spans_count_each_strip_inputs(tmp_path, job):
     stored = sum(s.info().rows * s.info().cols * s.info().bands * 2 for s in srcs)
     if job == "P3":
         assert want > stored
+
+
+def _write(path, info, regions):
+    w = TileWriter(str(path), tile_rows=16)
+    w.begin(info)
+    for region, data in regions:
+        w.consume(region, data)
+    w.end()
+    with open(path, "rb") as f:
+        return f.read()
+
+
+@pytest.mark.parametrize("job", ["P3", "P6"])
+def test_streamed_strips_reach_the_writer_c_ordered(tmp_path, job):
+    """Streamed into a ``TileWriter``, each strip comes from the plan
+    entry's host call with C-ordered rows in the file's dtype, so no
+    ``consume`` interleaves (``interleaved`` 0), and the file is byte for
+    byte the one the canonical program's strips give; for P6, an exact
+    conversion, also the eager oracle's.  On the CPU every array is row-major, so this guards
+    the contract, not a TPU's layout: ``chip_smoke.py`` checks that on the
+    chip."""
+    product = tmp_path / "product.rtic"
+    srcs, (p, m) = _stored_job(tmp_path, job, lambda: TileWriter(str(product), tile_rows=16))
+    try:
+        ex = StreamingExecutor(p, m, StripeSplitter(stripe_rows=STRIP))
+        strips = ex.my_regions()
+        with jax.profiler.trace(str(tmp_path / "trace")):
+            ex.run()
+        got = product.read_bytes()
+        info = p.info(m)
+        cache = PlanCache()
+        device_strips = []
+        for region in strips:
+            desc = p.describe_pull(m, region, virtual=p.virtual_describe_mode())
+            entry = cache.compiled_for(desc, lambda: p.lower_pull(desc))
+            out, _ = jax.jit(entry.canonical_fn)(
+                desc.read_sources(), desc.initial_pstates(), desc.origins()
+            )
+            assert out.shape == region.size + (info.bands,)
+            device_strips.append((region, np.asarray(out)))
+        assert got == _write(tmp_path / "device.rtic", info, device_strips)
+        if job == "P6":
+            eager = np.asarray(p.pull(m, info.full_region))
+            assert got == _write(tmp_path / "eager.rtic", info, [(info.full_region, eager)])
+    finally:
+        for s in srcs:
+            s.close()
+    consumes = [st for n, _, st in _program_spans(tmp_path / "trace") if n == "consume"]
+    assert len(consumes) == len(strips)
+    assert all(st["bytes"] > 0 and st["interleaved"] == 0 for st in consumes)
+
+
+@pytest.mark.parametrize("order", ["C", "F"])
+def test_consume_counts_the_bytes_it_interleaved(tmp_path, order):
+    """A region handed to ``TileWriter.consume`` columns-major, as a rank-3
+    strip pulled from a TPU comes, is interleaved on the host: the span's
+    ``interleaved`` is its level-0 bytes; a C-ordered one is written from
+    views (``interleaved`` 0).  Both give the same file."""
+    data = np.random.default_rng(3).integers(0, 255, (ROWS, COLS, BANDS), np.uint8)
+    info = ImageInfo(ROWS, COLS, BANDS, np.uint8)
+    region = whole(ROWS, COLS)
+    with jax.profiler.trace(str(tmp_path / "trace")):
+        got = _write(tmp_path / "product.rtic", info, [(region, np.asarray(data, order=order))])
+    assert got == _write(tmp_path / "c.rtic", info, [(region, data)])
+    (st,) = [st for n, _, st in _program_spans(tmp_path / "trace") if n == "consume"]
+    assert st["interleaved"] == (data.nbytes if order == "F" else 0)
+    assert st["bytes"] > data.nbytes  # level 0 and the overviews
