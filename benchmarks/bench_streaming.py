@@ -1,26 +1,24 @@
-"""Streaming-engine benchmark: plan-cache + double buffering vs seed behavior.
+"""Streaming-engine benchmark: plan-cache + double buffering.
 
-Three measurements on a uniform-stripe P2 (Haralick textures) run:
+Two measurements on a uniform-stripe P2 (Haralick textures) run:
 
-  * rejit_baseline — the seed semantics (``cache=False``): ``jax.jit`` of a
-    fresh closure every region, so every stripe retraces and recompiles;
-  * engine_cached  — the PlanCache path (one compile per signature), still
+  * engine_cached  — the PlanCache path (one compile per signature),
     synchronous (``prefetch=0``);
   * engine_async   — cached + double-buffered (``prefetch=2``), writing
     through the RTIF write-behind stage.
 
-Reported ``derived`` columns: regions/sec for the baseline row, speedup vs
-the baseline for the engine rows, compile count for the compile row (must be
-3 on striped P2: top/interior/bottom boundary signatures, of which only the
-interior one is hit repeatedly), and sequential/pool wall-time ratio for the
-work-stealing orchestrator row.
+Reported ``derived`` columns: regions/sec for the engine rows, registry hits
+for the compile row (one compile on striped P2: border stripes describe
+against the virtual padded geometry, so every stripe shares the interior
+signature), and sequential/pool wall-time ratio for the work-stealing
+orchestrator row.
 
 The ``plan_describe_vs_lower`` rows microbench the ExecutionPlan layer's
 cache-hit cost: a registry hit runs the describe pass only, so its per-region
 host overhead must beat the old hit path (describe **plus** rebuilding the
 O(graph) closure tree).  ``run(quick=True)`` (CI smoke: ``--quick``) keeps
 the cached-engine measurement and this microbench, and skips the slow
-baseline/I/O/pool sweeps.
+async/I/O/pool sweeps.
 """
 from __future__ import annotations
 
@@ -152,25 +150,15 @@ def run(quick: bool = False) -> List:
 
         _plan_layer_microbench(out, quick)
 
-        dt_rejit = None
-        if not quick:
-            # seed semantics: retrace + recompile every region
-            p, m = _p2(tmp, "rejit")
-            dt_rejit, res = _timed(
-                StreamingExecutor(p, m, splitter, cache=False, prefetch=0)
-            )
-            regions = res.regions_processed
-            out.append(("streaming_P2_rejit_baseline", dt_rejit * 1e6,
-                        regions / dt_rejit))
-
         # compiled-plan cache, synchronous loop
         p, m = _p2(tmp, "cached")
         cache = PlanCache()
-        dt_cached, _ = _timed(
+        dt_cached, res = _timed(
             StreamingExecutor(p, m, splitter, plan_cache=cache, prefetch=0)
         )
+        regions = res.regions_processed
         out.append(("streaming_P2_engine_cached", dt_cached * 1e6,
-                    (dt_rejit / dt_cached) if dt_rejit else 0.0))
+                    regions / dt_cached))
         out.append(("streaming_P2_compiles", float(cache.stats.compiles),
                     float(cache.stats.hits)))
         if cache.stats.compiles != 1:  # virtual border describes: one signature
@@ -186,15 +174,8 @@ def run(quick: bool = False) -> List:
         dt_async, _ = _timed(
             StreamingExecutor(p, m, splitter, plan_cache=PlanCache(), prefetch=2)
         )
-        out.append(("streaming_P2_engine_async", dt_async * 1e6, dt_rejit / dt_async))
+        out.append(("streaming_P2_engine_async", dt_async * 1e6, regions / dt_async))
         out.append(("streaming_P2_overlap", dt_async * 1e6, dt_cached / dt_async))
-
-        # the bar: engine ≥ 5× regions/sec over per-region re-jit (warn, don't
-        # abort the sweep — a loaded box can depress the ratio)
-        if dt_rejit / min(dt_cached, dt_async) < 5.0:
-            print(f"# WARNING: engine speedup below 5x "
-                  f"(rejit {dt_rejit:.2f}s, cached {dt_cached:.2f}s, "
-                  f"async {dt_async:.2f}s)", file=sys.stderr)
 
         # overlap on an I/O-bound pipeline (file → file copy): P2 above is
         # compute-bound, so double buffering shows its worth where the paper
@@ -232,7 +213,7 @@ def run(quick: bool = False) -> List:
         t0 = time.perf_counter()
         for w in range(n_workers):  # the seed orchestrator's sequential loop
             StreamingExecutor(
-                p, m, stage_splitter, worker=w, n_workers=n_workers, cache=False
+                p, m, stage_splitter, worker=w, n_workers=n_workers
             ).run()
         dt_seq = time.perf_counter() - t0
 

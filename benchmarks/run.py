@@ -60,8 +60,6 @@ SECTIONS = {
 _SNAPSHOT_METRICS = {
     "plan_describe_hit_cost_us": ("plan_describe_pass_us", "us_per_call"),
     "plan_lower_over_describe": ("plan_describe_pass_us", "derived"),
-    "streaming_speedup_vs_rejit": ("streaming_P2_engine_cached", "derived"),
-    "streaming_async_speedup_vs_rejit": ("streaming_P2_engine_async", "derived"),
     "streaming_compile_count": ("streaming_P2_compiles", "us_per_call"),
     # PR 9 tile-grid column: 2-D tiles vs 1-D strips on a wide image, and the
     # one-compile proof that every tile shares the interior signature

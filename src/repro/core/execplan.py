@@ -388,23 +388,6 @@ class PlanCache:
             self._entries.popitem(last=False)
             self.stats.evictions += 1
 
-    def compiled(self, plan: "PullPlan") -> _CompiledEntry:
-        """The compiled function for an already-lowered ``plan`` (the legacy
-        entry point: the caller paid the closure build regardless of hit or
-        miss).  Plans with equal signatures share one entry."""
-        key = plan.signature
-        with self._lock:
-            entry = self._entries.get(key)
-            if entry is not None:
-                self.stats.hits += 1
-                self._entries.move_to_end(key)
-                return entry
-            self.stats.misses += 1
-            self.stats.lowers += 1  # the caller lowered eagerly for this miss
-            entry = _CompiledEntry(plan.canonical_fn, self.stats)
-            self._store(key, entry)
-            return entry
-
     def compiled_for(
         self, desc: PlanDescription, lower: Callable[[], "PullPlan"]
     ) -> _CompiledEntry:

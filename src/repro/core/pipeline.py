@@ -727,15 +727,8 @@ class Pipeline:
             out = root(arrays, origins, ctx)
             return out, ctx["pstates"]
 
-        def legacy_fn(arrays, _origins=static_origins):
-            # seed-compatible entry point: origins baked in as constants
-            states = {p.name: p.reset() for p in persistent_nodes}
-            out, _ = canonical_fn(arrays, states, _origins)
-            return out
-
         return PullPlan(
             reads=reads,
-            fn=legacy_fn,
             out_region=out_region,
             canonical_fn=canonical_fn,
             signature=tuple(sig),
@@ -749,18 +742,16 @@ class Pipeline:
 
 @dataclasses.dataclass
 class PullPlan:
-    """``reads``: list of (source, clamped_region, requested_region);
-    ``fn(arrays)`` with arrays[i] covering reads[i]'s clamped region returns
-    the output pixels (origins baked in — the seed-compatible entry point).
+    """``reads``: list of (source, clamped_region, requested_region).
 
-    ``canonical_fn(arrays, pstates, origins)`` is the cacheable form:
+    ``canonical_fn(arrays, pstates, origins)``, with arrays[i] covering
+    reads[i]'s clamped region, returns ``(pixels, new_pstates)``:
     ``origins`` carries the absolute coordinates consumed by ``needs_origin``
     nodes as traced scalars and ``pstates`` threads persistent-filter state,
     so one jit of ``canonical_fn`` serves every region whose ``signature``
     matches this plan's."""
 
     reads: List[Tuple[Source, ImageRegion, ImageRegion]]
-    fn: Callable[[Sequence[jnp.ndarray]], jnp.ndarray]
     out_region: ImageRegion
     canonical_fn: Optional[Callable] = None
     signature: Tuple = ()
@@ -786,6 +777,3 @@ class PullPlan:
 
     def initial_pstates(self) -> Dict[str, Dict[str, jnp.ndarray]]:
         return {p.name: p.reset() for p in self.persistent_nodes}
-
-    def run(self) -> jnp.ndarray:
-        return self.fn(self.read_sources())

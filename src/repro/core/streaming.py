@@ -11,69 +11,53 @@ Three layers make the hot loop run at hardware speed:
      folds every shape/boundary-static quantity into a plan signature; the
      lower pass builds the closure threading absolute coordinates
      (``needs_origin``) and persistent-filter state through the pure function
-     as traced arguments.  Drifting warp requests are classified as
-     *windowed reads* (static-shape bounding windows, traced origins — see
+     as traced arguments, ``fn(arrays, pstates, origins) -> (pixels,
+     new_pstates)``.  Drifting warp requests become *windowed reads*
+     (static-shape bounding windows, traced origins — see
      ``ProcessObject.window_bound``), so a striped warp run shares ONE
      signature across every stripe, borders included.
-  2. **PlanCache** — the shared compiled-plan registry of the ExecutionPlan
-     layer (:mod:`repro.core.execplan`), keyed by plan signature.  A uniform
-     stripe split compiles exactly ONCE: border stripes describe against the
-     virtual padded geometry (no row clamping — the halo spill is
-     materialized by edge replication at the read stage, exactly like
-     windowed reads and the SPMD prober), so top/interior/bottom all share
-     the interior entry.  Pipelines whose persistent filters are not
-     mask-aware, or whose halo requests land on intermediate filters
-     (stacked neighborhood filters — see ``Pipeline.virtual_rows_safe``),
-     keep exact clamped describes (one entry per border
-     geometry).  Registry *hits* run the cheap describe pass only — the
-     lower pass (closure construction) happens on misses.
-     Hit/miss/compile/lower/eviction counts are surfaced in
-     ``StreamResult.cache_stats``; the same registry serves the SPMD
-     :class:`~repro.core.parallel.ParallelExecutor`, whose virtual padded
-     strips land on the very same interior entries (the shared read stage,
-     :func:`~repro.core.execplan.read_plan_sources`, clamps + edge-pads any
-     virtual row spill host-side, mirroring the SPMD halo replication), so
-     streaming→SPMD stays a registry hit on ragged and n=2 splits too.
-  3. **Async double buffering** — with ``prefetch=k``, source reads for the
-     next ``k`` regions run on a thread pool while the device computes the
-     current one, and ``mapper.consume`` is handed to a background writer
-     behind a bounded queue.  Windowed reads prefetch the full static-shape
-     window (edge-replicating any border spill host-side), so the hot loop
-     feeds fixed-shape buffers to one compiled function.  In-flight memory
-     stays bounded at roughly ``2·prefetch + 2`` region buffers (k
-     read-ahead + one computing + k + 1 queued writes), preserving the
-     paper's memory-budget guarantee with a constant factor.
+  2. **PlanCache** — the compiled-plan registry of the ExecutionPlan layer
+     (:mod:`repro.core.execplan`), keyed by plan signature and shared with
+     the SPMD :class:`~repro.core.parallel.ParallelExecutor`.  Border
+     stripes describe against the virtual padded geometry where
+     :meth:`Pipeline.virtual_describe_mode` allows it (the halo spill is
+     edge-replicated at the read stage), so a uniform stripe split compiles
+     exactly ONCE.  Registry hits run the describe pass only; the counts are
+     in ``StreamResult.cache_stats``.
+  3. **Async double buffering** — with ``prefetch=k``, the next ``k``
+     regions are described and read on a thread pool while the device
+     computes the current one, and ``mapper.consume`` runs on a write-behind
+     thread behind a bounded queue.  In-flight memory stays bounded at
+     roughly ``2·prefetch + 2`` region buffers (k read-ahead + one computing
+     + k + 1 queued writes), preserving the paper's memory-budget guarantee
+     with a constant factor.
 
-Pipelines containing :class:`PersistentFilter` nodes run through the compiled
-path too: state is carried across regions as
-``fn(arrays, pstates, origins) -> (pixels, new_pstates)``.
-
-The seed semantics stay reachable for A/B: ``use_jit=False`` is the eager
-pull, and ``cache=False`` restores the per-region re-jit behavior.
-
-``run_pool`` is the single-host concurrent driver: ``n_workers`` threads
-drain one shared :class:`~repro.core.scheduling.WorkStealingQueue` (or their
-static/LPT slices) against a shared :class:`PlanCache` — the dynamic load
-balancing the paper names as future work (§IV.C).
+Both host drivers run one per-region step (describe, gate, plan lookup and
+read; launch, wait and pull to the host; consume) through one region loop.
+:class:`StreamingExecutor` runs the loop once, on the calling thread, with
+read-ahead and write-behind; ``run_pool`` runs it on ``n_workers`` threads at
+read-ahead depth 0, draining one shared
+:class:`~repro.core.scheduling.WorkStealingQueue` (or their static/LPT
+slices) against a shared :class:`PlanCache` — the dynamic load balancing the
+paper names as future work (§IV.C).  With ``use_jit=False`` the step is the
+eager pull.
 """
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
+import functools
 import queue
 import threading
 from concurrent.futures import ThreadPoolExecutor
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, Iterable, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.execplan import (  # noqa: F401 — re-exported for back-compat
-    CacheStats,
-    PlanCache,
-    _CompiledEntry,
-)
+from repro.core.execplan import CacheStats, PlanCache, _CompiledEntry
 from repro.core.pipeline import Pipeline
 from repro.core.process_object import Mapper, PersistentFilter
 from repro.core.region import ImageRegion
@@ -88,30 +72,6 @@ from repro.core.splitting import Splitter, StripeSplitter
 from repro.core.tracing import span
 
 _SCHEDULERS = ("static", "lpt", "work_stealing")
-
-
-def _virtual_describe_mode(pipeline: Pipeline) -> "bool | str":
-    """The virtual describe mode the streaming drivers use for every strip
-    or tile: ``"grid"`` (no clamping in either axis), ``"rows"`` (rows only)
-    or ``False`` (exact clamped describes).  Structural conditions, decided
-    by :meth:`Pipeline.virtual_describe_mode`:
-
-      * any persistent filter must be mask-aware — under virtual geometry a
-        border region's accumulation can include edge-replicated pad pixels
-        that only a validity mask (``supports_mask``) keeps out of the
-        reduction;
-      * every spilling halo request on the virtualized axis must land
-        directly on a source (:meth:`Pipeline.virtual_rows_safe` /
-        :meth:`Pipeline.virtual_cols_safe`) — a halo landing on an
-        intermediate filter (stacked neighborhood filters) is clamped and
-        output-replicated by the exact walk but *computed* from replicated
-        source rows by the virtual walk, so those pipelines keep the exact
-        per-border describes to preserve the eager oracle's border pixels.
-
-    The SPMD tile prober (:func:`repro.core.parallel.build_tile_plan`) takes
-    its mode from the same method, so a streaming warm-up and a subsequent
-    grid run land on one registry entry."""
-    return pipeline.virtual_describe_mode()
 
 
 class _WriteBehind:
@@ -162,10 +122,177 @@ class StreamResult:
     persistent_results: Dict[str, Dict[str, jnp.ndarray]]
     #: per-region pixel outputs, only kept when ``keep_outputs=True``
     outputs: Optional[List[np.ndarray]] = None
-    #: plan-cache counters for this run (None on the eager / re-jit paths).
+    #: plan-cache counters for this run (None on the eager path).
     #: This is the LIVE CacheStats object — it keeps counting after the run
     #: (documented behavior, see ``reset_global_plan_cache``).
     cache_stats: Optional[CacheStats] = None
+
+
+@dataclasses.dataclass
+class _RegionStep:
+    """The per-region step both host drivers run, in the parts the spans
+    name.  :meth:`prepare` describes the region, waits on the region gate,
+    looks its program up in the plan registry and reads its sources (the
+    read-ahead half); :meth:`compute` launches the program, waits for it and
+    pulls the pixels to the host.  With ``use_jit=False`` prepare only
+    describes (and gates), and compute is the eager pull, whose hook
+    accumulates into the worker's persistent states."""
+
+    pipeline: Pipeline
+    mapper: Mapper
+    plan_cache: PlanCache
+    use_jit: bool
+    virtual: "bool | str"
+    #: region-availability gate (pipelined stage DAGs): ``wait(desc)`` blocks
+    #: until the rows the region reads are committed upstream, ``done(desc)``
+    #: releases them once the region's output has been consumed
+    region_gate: Optional[object] = None
+
+    def prepare(self, region: ImageRegion):
+        # describe pass only; the O(graph) closure tree is lowered by the
+        # registry on misses — cache hits never rebuild it.  Virtual geometry
+        # (when safe) folds border strips onto the interior signature.
+        with span("describe", row0=region.row0, col0=region.col0):
+            desc = self.pipeline.describe_pull(self.mapper, region, virtual=self.virtual)
+        if self.region_gate is not None:
+            self.region_gate.wait(desc)  # block until input rows commit
+        if not self.use_jit:
+            return region, desc, None, None
+        fn = self.plan_cache.compiled_for(desc, lambda: self.pipeline.lower_pull(desc))
+        with span("read", row0=region.row0, col0=region.col0) as sp:
+            arrays = desc.read_sources()
+            sp.set_metadata(bytes=sum(a.nbytes for a in arrays),
+                            inputs=len(arrays))
+        return region, desc, fn, arrays
+
+    def compute(self, prep, pstates) -> Tuple[np.ndarray, Dict]:
+        region, desc, fn, arrays = prep
+        # the device's own time is busy, not idle: inside ``dispatch`` only
+        # the program's launch and completion latency read as idle
+        with span("dispatch", row0=region.row0, col0=region.col0):
+            if fn is None:
+                pstates = dict(pstates)
+
+                def hook(node: PersistentFilter, reg: ImageRegion, inputs):
+                    pstates[node.name] = node.accumulate(
+                        pstates[node.name], reg, *inputs
+                    )
+
+                # eager pull; the hook observes every region exactly once
+                out = self.pipeline.pull(self.mapper, region, persistent_hook=hook)
+            else:
+                out, pstates = fn.launch(arrays, pstates, desc.origins())
+            jax.block_until_ready(out)
+        with span("d2h", row0=region.row0, col0=region.col0) as sp:
+            data = np.asarray(out) if fn is None else fn.to_host(out)
+            sp.set_metadata(bytes=data.nbytes)
+        return data, pstates
+
+
+class _LoopResult(NamedTuple):
+    regions: int
+    pixels: int
+    pstates: Dict
+    #: region index -> pixels, when the outputs are kept
+    kept: Dict[int, np.ndarray]
+
+
+def _region_loop(
+    step: _RegionStep,
+    regions: List[ImageRegion],
+    indices: Iterable[int],
+    consume: Callable[[ImageRegion, np.ndarray], None],
+    depth: int = 0,
+    keep_outputs: bool = False,
+) -> _LoopResult:
+    """One worker's region loop, on the calling thread: ``step`` over
+    ``regions[i]`` for each ``i`` that ``indices`` yields, with the worker's
+    own persistent states.  At ``depth`` k > 0 the next k regions are
+    prepared on read-ahead threads while the current one computes; at depth
+    0 prepare runs inline.  ``consume(region, data)`` hands each region's
+    pixels on (the mapper, or a write-behind queue); the region gate, if
+    any, releases a region once it is consumed."""
+    pstates = {p.name: p.reset() for p in step.pipeline.persistent_nodes()}
+    kept: Dict[int, np.ndarray] = {}
+    n = pixels = 0
+    it = iter(indices)
+    pending: "collections.deque" = collections.deque()
+    # the pool starts no thread at depth 0: nothing is submitted to it
+    with ThreadPoolExecutor(
+        max_workers=max(1, depth), thread_name_prefix="prefetch"
+    ) as pool:
+
+        def fill(k: int) -> None:
+            while len(pending) < k:
+                i = next(it, None)
+                if i is None:
+                    return
+                fut = pool.submit(step.prepare, regions[i]) if depth else None
+                pending.append((i, fut))
+
+        try:
+            while True:
+                fill(max(depth, 1))
+                if not pending:
+                    break
+                i, fut = pending.popleft()
+                region = regions[i]
+                with span("wait_inputs", row0=region.row0, col0=region.col0):
+                    prep = step.prepare(region) if fut is None else fut.result()
+                fill(depth)  # keep the read window full while we compute
+                data, pstates = step.compute(prep, pstates)
+                with span("wait_write", row0=region.row0, col0=region.col0):
+                    consume(region, data)
+                if step.region_gate is not None:
+                    step.region_gate.done(prep[1])  # release its input rows
+                n += 1
+                pixels += region.num_pixels
+                if keep_outputs:
+                    kept[i] = data
+        finally:
+            for _, fut in pending:
+                if fut is not None:
+                    fut.cancel()
+    return _LoopResult(n, pixels, pstates, kept)
+
+
+def _run_pass(
+    step: _RegionStep,
+    run_loops: Callable[[], List[_LoopResult]],
+    keep_outputs: bool,
+) -> StreamResult:
+    """One pass over the image: ``mapper.begin``, the region loops
+    ``run_loops`` runs (one result per worker), then the paper's Synthesis
+    of the workers' combined persistent states and ``mapper.end``, which
+    also runs when a loop fails (releasing writer descriptors)."""
+    pipeline, mapper = step.pipeline, step.mapper
+    info = pipeline.info(mapper)
+    with span("begin"):
+        mapper.begin(info)
+    try:
+        loops = run_loops()
+    except BaseException:
+        with contextlib.suppress(Exception):
+            mapper.end()
+        raise
+    persistent = pipeline.persistent_nodes()
+    states = loops[0].pstates
+    for loop in loops[1:]:
+        states = {
+            p.name: p.combine_states(states[p.name], loop.pstates[p.name])
+            for p in persistent
+        }
+    presults = {p.name: p.synthesize(states[p.name]) for p in persistent}
+    with span("end"):
+        mapper.end()
+    kept = {i: data for loop in loops for i, data in loop.kept.items()}
+    return StreamResult(
+        regions_processed=sum(loop.regions for loop in loops),
+        pixels_processed=sum(loop.pixels for loop in loops),
+        persistent_results=presults,
+        outputs=[kept[i] for i in sorted(kept)] if keep_outputs else None,
+        cache_stats=step.plan_cache.stats if step.use_jit else None,
+    )
 
 
 class StreamingExecutor:
@@ -179,11 +306,9 @@ class StreamingExecutor:
         scheduler: str = "static",
         cost_fn: Optional[Callable[[ImageRegion], float]] = None,
         use_jit: bool = True,
-        cache: bool = True,
         plan_cache: Optional[PlanCache] = None,
         prefetch: int = 2,
         max_cached_plans: Optional[int] = None,
-        region_gate=None,
     ):
         if scheduler not in _SCHEDULERS:
             raise ValueError(scheduler)
@@ -195,23 +320,15 @@ class StreamingExecutor:
         self.scheduler = scheduler
         self.cost_fn = cost_fn or (lambda r: float(r.num_pixels))
         self.use_jit = use_jit
-        self.cache = cache
         # explicit None check: an empty PlanCache is falsy (it has __len__)
         self.plan_cache = (
             plan_cache if plan_cache is not None else PlanCache(max_cached_plans)
         )
         self.prefetch = max(0, int(prefetch))
-        # region-availability gate (pipelined stage DAGs): wait(desc) blocks
-        # until the rows the region reads are committed upstream; done(desc)
-        # releases them once the region's output has been handed off
-        self.region_gate = region_gate
-        # Border strips describe against the virtual padded geometry (like the
-        # SPMD prober), so a striped halo run shares ONE interior signature:
-        # the row spill of border halos is materialized at the read stage
-        # instead of being clamped into a per-border plan.  Persistent filters
-        # that are not mask-aware would accumulate the replicated pad rows, so
-        # those pipelines keep the exact clamped describes.
-        self.describe_virtual = _virtual_describe_mode(pipeline)
+        # border strips describe against the virtual padded geometry (like
+        # the SPMD prober) where the pipeline allows it, so a striped halo run
+        # shares ONE interior signature
+        self.describe_virtual = pipeline.virtual_describe_mode()
 
     def my_regions(self) -> List[ImageRegion]:
         info = self.pipeline.info(self.mapper)
@@ -224,174 +341,40 @@ class StreamingExecutor:
             sched = work_stealing_schedule(regions, self.n_workers, self.cost_fn)
         return [regions[i] for i in sched[self.worker]]
 
-    # -- the prefetch stage: host-side planning + source reads ----------------
-    def _prepare(self, region: ImageRegion):
-        # describe pass only; the O(graph) closure tree is lowered by the
-        # registry on misses — cache hits never rebuild it.  Virtual geometry
-        # (when safe) folds border strips onto the interior signature.
-        with span("describe", row0=region.row0, col0=region.col0):
-            desc = self.pipeline.describe_pull(
-                self.mapper, region, virtual=self.describe_virtual
-            )
-        if self.region_gate is not None:
-            # block (on the prefetch thread) until the input rows this region
-            # actually reads are committed by the upstream stage
-            self.region_gate.wait(desc)
-        fn = self.plan_cache.compiled_for(
-            desc, lambda: self.pipeline.lower_pull(desc)
-        )
-        with span("read", row0=region.row0, col0=region.col0) as sp:
-            arrays = desc.read_sources()
-            sp.set_metadata(bytes=sum(a.nbytes for a in arrays),
-                            inputs=len(arrays))
-        return desc, fn, arrays
-
     def run(self, keep_outputs: bool = False) -> StreamResult:
-        pipeline, mapper = self.pipeline, self.mapper
-        info = pipeline.info(mapper)
-        with span("begin"):
-            mapper.begin(info)
-
-        # persistent-filter state lives across regions (paper's Reset)
-        pstates = {p.name: p.reset() for p in pipeline.persistent_nodes()}
-
-        def hook(node: PersistentFilter, region: ImageRegion, inputs):
-            pstates[node.name] = node.accumulate(pstates[node.name], region, *inputs)
-
-        outputs: List[np.ndarray] = []
-        pixels = 0
-        regions = self.my_regions()
-        compiled_path = self.use_jit and self.cache
-
-        # hand the region schedule to range-readable sources before the
-        # region loop: tiled/remote sources (RasterSource.read_ahead)
-        # prefetch the covering tiles on their own thread, overlapping range
-        # fetches with plan execution.  A best-effort hint — sources clamp
-        # the schedule to their own geometry and plain sources ignore it.
-        for src in pipeline.sources():
-            ra = getattr(src, "read_ahead", None)
-            if callable(ra):
-                ra(regions)
-
-        def compute(prep) -> np.ndarray:
-            nonlocal pstates
-            plan, fn, arrays = prep
-            r = plan.out_region
-            # the device's own time is busy, not idle: inside ``dispatch``
-            # only the program's launch and completion latency read as idle
-            with span("dispatch", row0=r.row0, col0=r.col0):
-                out, pstates = fn.launch(arrays, pstates, plan.origins())
-                if self.region_gate is not None:
-                    # pacing-only release (the data lives on disk): fire once
-                    # the region's pixels are produced and handed to the
-                    # write stage
-                    self.region_gate.done(plan)
-                jax.block_until_ready(out)
-            with span("d2h", row0=r.row0, col0=r.col0) as sp:
-                data = fn.to_host(out)
-                sp.set_metadata(bytes=data.nbytes)
-                return data
-
-        def produce_sync(region: ImageRegion) -> np.ndarray:
-            if compiled_path:
-                with span("wait_inputs", row0=region.row0, col0=region.col0):
-                    prep = self._prepare(region)
-                return compute(prep)
-            if self.region_gate is not None:
-                # non-compiled paths still gate on the described reads (the
-                # gate clamps virtual row spill to the committed extent)
-                desc = pipeline.describe_pull(
-                    mapper, region, virtual=self.describe_virtual
-                )
-                self.region_gate.wait(desc)
-                self.region_gate.done(desc)
-            if self.use_jit and not pipeline.persistent_nodes():
-                # cache=False A/B baseline: the seed's per-region re-jit
-                plan = pipeline.compile_pull(mapper, region)
-                return np.asarray(jax.jit(plan.fn)(plan.read_sources()))
-            # eager pull; the hook observes every region exactly once
-            return np.asarray(pipeline.pull(mapper, region, persistent_hook=hook))
-
-        try:
-            if compiled_path and self.prefetch > 0 and len(regions) > 1:
-                pixels = self._run_async(regions, compute, outputs, keep_outputs)
-            else:
-                for region in regions:
-                    data = produce_sync(region)
-                    with span("wait_write", row0=region.row0, col0=region.col0):
-                        mapper.consume(region, data)
-                    pixels += region.num_pixels
-                    if keep_outputs:
-                        outputs.append(data)
-        except BaseException:
-            try:
-                mapper.end()  # release writer descriptors on the error path
-            except Exception:
-                pass
-            raise
-
-        # paper's Synthesis: finalize persistent state after the region loop
-        presults = {
-            p.name: p.synthesize(pstates[p.name]) for p in pipeline.persistent_nodes()
-        }
-        with span("end"):
-            mapper.end()
-        return StreamResult(
-            regions_processed=len(regions),
-            pixels_processed=pixels,
-            persistent_results=presults,
-            outputs=outputs if keep_outputs else None,
-            cache_stats=self.plan_cache.stats if compiled_path else None,
+        """The region loop once, on the calling thread: reads for the next
+        ``prefetch`` regions overlap the device computing the current one,
+        and writes trail behind on their own thread."""
+        step = _RegionStep(
+            self.pipeline, self.mapper, self.plan_cache, self.use_jit,
+            self.describe_virtual,
         )
 
-    def _run_async(self, regions, compute, outputs, keep_outputs) -> int:
-        """Double-buffered loop: reads for region i+1..i+prefetch overlap the
-        device computing region i; writes trail behind on their own thread."""
-        depth = self.prefetch
-        pixels = 0
-        writer = _WriteBehind(self.mapper.consume, depth + 1)
-        pending: "collections.deque" = collections.deque()
-        nxt = 0
-        error: Optional[BaseException] = None
-        with ThreadPoolExecutor(
-            max_workers=depth, thread_name_prefix="prefetch"
-        ) as pool:
-
-            def fill():
-                nonlocal nxt
-                while nxt < len(regions) and len(pending) < depth:
-                    pending.append(
-                        (regions[nxt], pool.submit(self._prepare, regions[nxt]))
-                    )
-                    nxt += 1
-
+        def loop() -> List[_LoopResult]:
+            regions = self.my_regions()
+            # a best-effort hint: tiled/remote sources (RasterSource.read_ahead)
+            # fetch the schedule's covering tiles on their own thread; plain
+            # sources ignore it
+            for src in self.pipeline.sources():
+                ra = getattr(src, "read_ahead", None)
+                if callable(ra):
+                    ra(regions)
+            order = range(len(regions))
+            # read-ahead pays only over several regions, and the eager pull
+            # has no read stage to run ahead
+            depth = self.prefetch if self.use_jit and len(regions) > 1 else 0
+            if not depth:
+                return [_region_loop(step, regions, order, self.mapper.consume,
+                                     0, keep_outputs)]
+            writer = _WriteBehind(self.mapper.consume, depth + 1)
             try:
-                fill()
-                while pending:
-                    region, fut = pending.popleft()
-                    with span("wait_inputs", row0=region.row0, col0=region.col0):
-                        prep = fut.result()
-                    fill()  # keep the read window full while we compute
-                    data = compute(prep)
-                    pixels += region.num_pixels
-                    if keep_outputs:
-                        outputs.append(data)
-                    with span("wait_write", row0=region.row0, col0=region.col0):
-                        writer.put(region, data)
-            except BaseException as e:  # noqa: BLE001 — re-raised below
-                error = e
+                return [_region_loop(step, regions, order, writer.put,
+                                     depth, keep_outputs)]
             finally:
-                for _, fut in pending:
-                    fut.cancel()
-                try:
-                    with span("wait_write"):
-                        writer.close()
-                except BaseException as e:  # noqa: BLE001
-                    if error is None:
-                        error = e
-        if error is not None:
-            raise error
-        return pixels
+                with span("wait_write"):
+                    writer.close()
+
+        return _run_pass(step, loop, keep_outputs)
 
 
 def run_pool(
@@ -408,29 +391,25 @@ def run_pool(
     region_gate=None,
     in_order: bool = False,
 ) -> StreamResult:
-    """Run one pipeline with ``n_workers`` concurrent threads on this host.
+    """Run one pipeline with ``n_workers`` concurrent threads on this host,
+    each running the region loop at read-ahead depth 0.
 
     With ``scheduler="work_stealing"`` the workers drain one shared
     :class:`WorkStealingQueue` (idle workers steal from the most-loaded
     victim's tail); ``"static"`` / ``"lpt"`` give each worker its precomputed
-    slice but still run the slices concurrently.  All workers share one
-    :class:`PlanCache`, so a uniform split still compiles once.  Per-worker
-    persistent states are combined with the filters' reductions, then
-    synthesized once — the thread-level analogue of the paper's MPI
-    many-to-one Synthesis.
+    slice.  All workers share one :class:`PlanCache`, so a uniform split
+    still compiles once.  Per-worker persistent states are combined with the
+    filters' reductions, then synthesized once — the thread-level analogue
+    of the paper's MPI many-to-one Synthesis.
 
     ``region_gate`` (pipelined stage DAGs, :mod:`repro.core.dag`) makes the
-    workers block *per region*: each region's describe pass runs first, the
-    gate waits until the input rows it reads are committed upstream, and the
-    gate releases them after the region's output is consumed.  Gated runs
-    hand regions out in strict region order (:class:`FifoQueue`) regardless
-    of ``scheduler`` — readiness follows the producer's commit frontier, so
-    in-order hand-out keeps every worker on ready (or soonest-ready) regions
-    and the per-edge in-flight window bounded.  ``in_order=True`` forces the
-    same FIFO hand-out on an *ungated* run: the pipelined orchestrator sets
-    it on producer stages so strips are offered downstream in the consumers'
-    row order and backpressure tracks the real commit frontier instead of a
-    work-stealing shuffle."""
+    workers block *per region*: after a region's describe pass the gate
+    waits until the input rows it reads are committed upstream, and it
+    releases them once the region's output is consumed.  Gated runs, and
+    runs with ``in_order=True`` (the pipelined orchestrator's producer
+    stages), hand regions out in strict region order (:class:`FifoQueue`)
+    whatever the ``scheduler``, so workers stay on the regions the commit
+    frontier makes ready first and backpressure tracks that frontier."""
     if scheduler not in _SCHEDULERS:
         raise ValueError(scheduler)
     n_workers = max(1, int(n_workers))
@@ -438,128 +417,48 @@ def run_pool(
     splitter = splitter or StripeSplitter(n_splits=n_workers * 4)
     regions = splitter.split(info.full_region, info)
     cost = cost_fn or (lambda r: float(r.num_pixels))
-    cache = plan_cache if plan_cache is not None else PlanCache()
-
-    mapper.begin(info)
-    consume_lock = (
-        None if getattr(mapper, "thread_safe", False) else threading.Lock()
+    # the same border-strip virtualization as StreamingExecutor: all
+    # workers land on the one interior signature (one lower + compile)
+    step = _RegionStep(
+        pipeline, mapper, plan_cache if plan_cache is not None else PlanCache(),
+        use_jit, pipeline.virtual_describe_mode(), region_gate,
     )
+    lock = None if getattr(mapper, "thread_safe", False) else threading.Lock()
 
-    def consume(region, data):
-        if consume_lock is None:
+    def consume(region: ImageRegion, data: np.ndarray) -> None:
+        with lock or contextlib.nullcontext():
             mapper.consume(region, data)
-        else:
-            with consume_lock:
-                mapper.consume(region, data)
-
-    persistent = pipeline.persistent_nodes()
-    # same border-strip virtualization as StreamingExecutor._prepare: all
-    # workers then land on the one interior signature (single lower+compile)
-    describe_virtual = _virtual_describe_mode(pipeline)
-    worker_states = [{p.name: p.reset() for p in persistent} for _ in range(n_workers)]
-    counts = [0] * n_workers
-    pixel_counts = [0] * n_workers
-    outputs_by_index: Optional[Dict[int, np.ndarray]] = {} if keep_outputs else None
 
     if region_gate is not None or in_order:
-        fifo = FifoQueue(len(regions))
-
-        def indices(w):
-            while True:
-                i = fifo.take(w)
-                if i is None:
-                    return
-                yield i
-
+        shared = FifoQueue(len(regions))
     elif scheduler == "work_stealing":
-        wsq = WorkStealingQueue(
+        shared = WorkStealingQueue(
             len(regions), n_workers, costs=[cost(r) for r in regions]
         )
-
-        def indices(w):
-            while True:
-                i = wsq.take(w)
-                if i is None:
-                    return
-                yield i
-
     else:
+        shared = None
         sched = (
             static_schedule(regions, n_workers)
             if scheduler == "static"
             else lpt_schedule(regions, n_workers, cost)
         )
 
-        def indices(w):
-            return iter(sched[w])
+    def work(w: int) -> _LoopResult:
+        indices = (sched[w] if shared is None
+                   else iter(functools.partial(shared.take, w), None))
+        return _region_loop(step, regions, indices, consume,
+                            keep_outputs=keep_outputs)
 
-    def work(w: int) -> None:
-        pstates = worker_states[w]
-
-        def hook(node, reg, inputs):
-            pstates[node.name] = node.accumulate(pstates[node.name], reg, *inputs)
-
-        for i in indices(w):
-            region = regions[i]
-            desc = None
-            if use_jit or region_gate is not None:
-                desc = pipeline.describe_pull(
-                    mapper, region, virtual=describe_virtual
-                )
-                if region_gate is not None:
-                    region_gate.wait(desc)  # block until input rows commit
-            if use_jit:
-                fn = cache.compiled_for(desc, lambda: pipeline.lower_pull(desc))
-                data, pstates = fn.host(
-                    desc.read_sources(), pstates, desc.origins()
-                )
-            else:
-                data = np.asarray(
-                    pipeline.pull(mapper, region, persistent_hook=hook)
-                )
-            consume(region, data)
-            if region_gate is not None:
-                region_gate.done(desc)  # region consumed: release input rows
-            counts[w] += 1
-            pixel_counts[w] += region.num_pixels
-            if outputs_by_index is not None:
-                outputs_by_index[i] = data
-        worker_states[w] = pstates
-
-    try:
+    def loops() -> List[_LoopResult]:
         if n_workers == 1:
-            work(0)
-        else:
-            with ThreadPoolExecutor(
-                max_workers=n_workers, thread_name_prefix="pool"
-            ) as pool:
-                futs = [pool.submit(work, w) for w in range(n_workers)]
-                for f in futs:
-                    f.result()
-    except BaseException:
-        try:
-            mapper.end()  # release writer descriptors on the error path
-        except Exception:
-            pass
-        raise
+            return [work(0)]
+        with ThreadPoolExecutor(
+            max_workers=n_workers, thread_name_prefix="pool"
+        ) as pool:
+            futs = [pool.submit(work, w) for w in range(n_workers)]
+            return [f.result() for f in futs]
 
-    combined = {p.name: worker_states[0][p.name] for p in persistent}
-    for states in worker_states[1:]:
-        for p in persistent:
-            combined[p.name] = p.combine_states(combined[p.name], states[p.name])
-    presults = {p.name: p.synthesize(combined[p.name]) for p in persistent}
-    mapper.end()
-    return StreamResult(
-        regions_processed=sum(counts),
-        pixels_processed=sum(pixel_counts),
-        persistent_results=presults,
-        outputs=(
-            [outputs_by_index[i] for i in sorted(outputs_by_index)]
-            if outputs_by_index is not None
-            else None
-        ),
-        cache_stats=cache.stats if use_jit else None,
-    )
+    return _run_pass(step, loops, keep_outputs)
 
 
 class BatchedRegionPuller:
@@ -582,7 +481,7 @@ class BatchedRegionPuller:
     makes tile outputs depend on request order, which serving cannot honor.
 
     ``virtual`` should carry the same describe mode the streaming oracle
-    would pick (:func:`_virtual_describe_mode`), so tile signatures collapse
+    would pick (:meth:`Pipeline.virtual_describe_mode`), so tile signatures collapse
     onto the entries a streaming warm-up run already lowered.
 
     ``read_cache_entries`` bounds an LRU of per-region source reads (the
@@ -614,7 +513,7 @@ class BatchedRegionPuller:
         if not self.batch_sizes or self.batch_sizes[0] < 1:
             raise ValueError(f"bad batch_sizes: {batch_sizes}")
         self.virtual = (
-            _virtual_describe_mode(pipeline) if virtual is None else virtual
+            pipeline.virtual_describe_mode() if virtual is None else virtual
         )
         self.read_cache_entries = int(read_cache_entries)
         self._read_cache: "collections.OrderedDict[Tuple, List]" = (

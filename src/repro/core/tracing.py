@@ -15,16 +15,19 @@ share them; ``bytes`` is what that span moved.
 The spans, by thread (this list is the contract the benchmark's readers and
 operators rely on):
 
-The thread that dispatches the device programs
-(``StreamingExecutor.run``).  Its spans do not overlap and cover its pass
-but for a little glue between them, so each idle second of the chip lies
-in one of them or in that small remainder:
+A thread that dispatches the device programs: the one that calls
+``StreamingExecutor.run``, or each worker of ``run_pool``; both run the
+same region loop.  Its spans do not overlap and cover its pass but for a
+little glue between them, so each idle second of the chip lies in one of
+them or in that small remainder.  ``begin`` and ``end`` frame the pass
+once, on the calling thread:
 
 - ``begin``: ``mapper.begin`` before the first strip (a file sink truncates
   and lays out its file);
 - ``wait_inputs`` (``row0``, ``col0``): waiting for a strip's prefetched
-  inputs (the describe pass, plan lookup and source read), or, with
-  ``prefetch=0``, doing that work inline;
+  inputs (the describe pass, plan lookup and source read), or, at
+  read-ahead depth 0 (``prefetch=0``, and every ``run_pool`` worker),
+  doing that work inline;
 - ``dispatch`` (``row0``, ``col0``): enqueueing the strip's program and
   waiting until the device has run it; the device is busy for most of it,
   and what idles there is the program's launch and completion latency;
@@ -33,13 +36,13 @@ in one of them or in that small remainder:
   ``bytes`` are the strip's pixel bytes (not the lane padding its rows
   travel with);
 - ``wait_write`` (``row0``, ``col0``): handing a strip to the write stage,
-  blocked while the write-behind queue is full, or, with ``prefetch=0``,
-  the inline ``mapper.consume``; without an origin, the drain of the
-  write-behind queue at the end of the pass;
+  blocked while the write-behind queue is full, or, at depth 0, the inline
+  ``mapper.consume``; without an origin, the drain of the write-behind
+  queue at the end of the pass;
 - ``end``: ``mapper.end`` after the last strip (a file sink writes its
   index and seals its header).
 
-The prefetch threads (with ``prefetch=0``, the dispatching thread, inside
+The prefetch threads (at depth 0, the dispatching thread, inside
 ``wait_inputs``):
 
 - ``describe`` (``row0``, ``col0``): the describe pass of a strip;
@@ -49,8 +52,7 @@ The prefetch threads (with ``prefetch=0``, the dispatching thread, inside
   Rows an input's windows share across strip seams count in each strip.
 
 The thread that runs ``TileWriter.consume`` (the write-behind thread, or
-the dispatching thread with ``prefetch=0``) and the one that runs its
-``end``:
+the dispatching thread at depth 0) and the one that runs its ``end``:
 
 - ``consume`` (``row0``, ``col0``, ``bytes``, ``ranges``, ``interleaved``):
   one region written straight to its tiles' byte ranges at every pyramid

@@ -59,8 +59,8 @@ def _property(seed, *ranges):
         if HAVE_HYPOTHESIS:
             strategies = [st.integers(lo, hi) for lo, hi in ranges]
 
-            @given(*strategies)
-            def wrapper(*args):
+            @given(st.tuples(*strategies))
+            def wrapper(args):
                 check(*args)
 
             return wrapper

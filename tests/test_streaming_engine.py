@@ -3,8 +3,9 @@
 Covers the three engine layers: canonical plans share one compiled function
 per (shape, boundary, plan-key) signature; prefetch/write-behind is
 bit-identical to the synchronous loop; persistent filters run through the
-compiled path with state bit-identical to the eager oracle; and the
-work-stealing pool drains every region exactly once.
+compiled path with state bit-identical to the eager oracle; the
+work-stealing pool drains every region exactly once; and every driver of
+the shared region loop matches the whole-image pull.
 """
 import threading
 
@@ -133,20 +134,6 @@ def test_plan_cache_lru_eviction():
     assert cache.stats.compiles == 2
     assert cache.stats.evictions == 1
     assert len(cache) == 1
-
-
-def test_rejit_baseline_never_caches():
-    """cache=False keeps the seed's per-region re-jit semantics reachable."""
-    p, m = PP.p6_conversion(_src(48, 32))
-    cache = PlanCache()
-    res = StreamingExecutor(
-        p, m, StripeSplitter(n_splits=4), plan_cache=cache, cache=False
-    ).run()
-    assert res.cache_stats is None
-    assert cache.stats.compiles == 0
-    p2, m2 = PP.p6_conversion(_src(48, 32))
-    whole = np.asarray(p2.pull(m2, p2.info(m2).full_region))
-    np.testing.assert_array_equal(m.result, whole)
 
 
 def test_p3_registered_in_pipeline_registry():
@@ -405,3 +392,53 @@ def test_run_pool_eager_path():
     np.testing.assert_allclose(
         np.asarray(stats["mean"]), img.reshape(-1, 3).mean(0), rtol=1e-4
     )
+
+
+# -- the shared region loop, under every driver ------------------------------
+REGION_LOOP_DRIVERS = {
+    "streaming-prefetch0": lambda p, m, s, jit: StreamingExecutor(
+        p, m, s, prefetch=0, use_jit=jit).run(keep_outputs=True),
+    "streaming-prefetch2": lambda p, m, s, jit: StreamingExecutor(
+        p, m, s, prefetch=2, use_jit=jit).run(keep_outputs=True),
+    "pool-1": lambda p, m, s, jit: run_pool(
+        p, m, s, n_workers=1, use_jit=jit, keep_outputs=True),
+    "pool-3": lambda p, m, s, jit: run_pool(
+        p, m, s, n_workers=3, use_jit=jit, keep_outputs=True),
+}
+
+
+@pytest.mark.parametrize("use_jit", [True, False], ids=["jit", "eager"])
+@pytest.mark.parametrize("driver", list(REGION_LOOP_DRIVERS))
+def test_region_loop_matches_whole_image_pull(driver, use_jit):
+    """Whichever driver runs the region loop, and on the compiled or the
+    eager step, a pipeline with a persistent filter gives the whole-image
+    pull's pixels (in the sink and in the kept outputs, in region order)
+    and its synthesized statistics."""
+    p, m = _stats_pipeline(40, 30)
+    res = REGION_LOOP_DRIVERS[driver](p, m, StripeSplitter(n_splits=6), use_jit)
+
+    p2, m2 = _stats_pipeline(40, 30)
+    (node,) = p2.persistent_nodes()
+    state = {node.name: node.reset()}
+
+    def hook(n, region, inputs):
+        state[n.name] = n.accumulate(state[n.name], region, *inputs)
+
+    whole = np.asarray(p2.pull(m2, p2.info(m2).full_region, persistent_hook=hook))
+    want = node.synthesize(state[node.name])
+
+    assert (res.cache_stats is not None) == use_jit
+    assert res.regions_processed == 6
+    assert res.pixels_processed == 40 * 30
+    np.testing.assert_array_equal(m.result, whole)
+    np.testing.assert_array_equal(np.concatenate(res.outputs, axis=0), whole)
+    got = res.persistent_results[node.name]
+    assert set(got) == set(want)
+    # the regions' partial sums add up in another order; std is
+    # sqrt(E[x²] - mean²), which cancels, so it keeps the tolerance of
+    # test_run_pool_persistent_stats_any_scheduler
+    for k in want:
+        np.testing.assert_allclose(
+            np.asarray(got[k]), np.asarray(want[k]),
+            rtol=1e-3 if k == "std" else 1e-5, err_msg=k,
+        )

@@ -12,8 +12,10 @@ import numpy as np
 import pytest
 
 from repro import pipelines as PP
-from repro.core import ImageInfo, PlanCache, StreamingExecutor, StripeSplitter, whole
-from repro.raster import TiledSource, TileWriter
+from repro.core import (
+    ImageInfo, PlanCache, StreamingExecutor, StripeSplitter, run_pool, whole,
+)
+from repro.raster import SyntheticScene, TiledSource, TileWriter
 from repro.raster.tiled import TILED_HEADER_BYTES, TILED_MAGIC
 
 ROWS, COLS, BANDS, STRIP = 44, 30, 3, 8  # a ragged last strip
@@ -96,6 +98,35 @@ def test_streamed_pass_emits_every_span(tmp_path, prefetch):
         head = f.read(TILED_HEADER_BYTES)
     meta = json.loads(head[len(TILED_MAGIC):].rstrip(b"\0"))
     assert total["flush"] == meta["index_length"] + TILED_HEADER_BYTES
+
+
+def test_each_pool_worker_is_a_dispatching_thread(tmp_path):
+    """``run_pool`` runs the same region loop on each worker thread: with
+    two workers, ``dispatch``, ``d2h`` and ``wait_write`` come from two
+    threads, each with the origins of the strips that worker ran, the two
+    together every strip once; ``begin`` and ``end`` frame the pass once,
+    on the calling thread."""
+    p, m = PP.p6_conversion(SyntheticScene(ROWS, COLS, bands=BANDS, dtype=np.float32))
+    splitter = StripeSplitter(stripe_rows=STRIP)
+    info = p.info(m)
+    strips = splitter.split(info.full_region, info)
+    with jax.profiler.trace(str(tmp_path / "trace")):
+        run_pool(p, m, splitter, n_workers=2, scheduler="static")
+    spans = _program_spans(tmp_path / "trace")
+
+    count = collections.Counter(n for n, _, _ in spans)
+    assert count["begin"] == count["end"] == 1
+    workers = collections.defaultdict(lambda: collections.defaultdict(list))
+    for n, line, st in spans:
+        if n in ("dispatch", "d2h", "wait_write"):
+            workers[line][n].append((st["row0"], st["col0"]))
+    assert len(workers) == 2
+    for names in workers.values():
+        assert names["dispatch"] and names["dispatch"] == names["d2h"] == names["wait_write"]
+    ran = sorted(o for names in workers.values() for o in names["dispatch"])
+    assert ran == sorted((r.row0, r.col0) for r in strips)
+    (frame,) = {line for n, line, _ in spans if n in ("begin", "end")}
+    assert frame not in workers
 
 
 def _described_bytes(p, m, region):
